@@ -15,7 +15,6 @@ import os
 from typing import Dict
 
 from repro.configs import INPUT_SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 from repro.models import model as M
 
 LEDGER = os.environ.get("DRYRUN_LEDGER", "experiments/dryrun.json")

@@ -75,6 +75,9 @@ def smoke(rows) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", type=str, default=None,
                     help="comma-separated subset of " + ",".join(SUITES))

@@ -187,6 +187,14 @@ def _concat(eqn, out_idx, out_dim):
     return {i: out_dim for i in range(len(eqn.invars))}
 
 
+@register("split")
+def _split(eqn, out_idx, out_dim):
+    # every piece keeps the input's extent off the split axis
+    if out_dim == eqn.params["axis"]:
+        return None
+    return {0: out_dim}
+
+
 @register("slice")
 def _slice(eqn, out_idx, out_dim):
     p = eqn.params

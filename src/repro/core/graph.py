@@ -24,8 +24,7 @@ JaxprEqn = Any
 
 # Call-like primitives that we inline so the pass pipeline sees a flat graph.
 _CALL_PRIM_NAMES = {
-    "jit",   # nested jax.jit / jnp internal wrappers (jax>=0.7 name)
-    "pjit",  # older name, kept for compatibility
+    "jit",   # nested jax.jit / jnp internal wrappers
     "closed_call",
     "custom_jvp_call",
     "custom_vjp_call",
@@ -36,28 +35,8 @@ _CALL_PRIM_NAMES = {
 
 
 def fresh_var(aval) -> Var:
-    """Make a new Var for ``aval`` across jax versions.
-
-    jax<=0.4.35 exposes ``Var(aval)``; newer releases take ``Var(suffix,
-    aval)``.  Probe once at import time instead of try/except per call.
-    """
-    return Var(*_VAR_PREFIX_ARGS, aval)
-
-
-def _probe_var_prefix_args():
-    # derive a real aval from a trivial trace rather than naming
-    # jax.core.ShapedArray (deprecated alias, removed in newer jax)
-    aval = jax.make_jaxpr(lambda x: x)(0.0).jaxpr.outvars[0].aval
-    for prefix in ((), ("",)):
-        try:
-            Var(*prefix, aval)
-            return prefix
-        except TypeError:
-            continue
-    raise RuntimeError("unsupported jax.extend.core.Var signature")
-
-
-_VAR_PREFIX_ARGS = _probe_var_prefix_args()
+    """A new Var of abstract type ``aval``."""
+    return Var(aval)
 
 
 def aval_bytes(aval) -> int:

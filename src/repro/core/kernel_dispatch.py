@@ -39,8 +39,9 @@ returned :class:`~repro.kernels.autotune.KernelTuning` in the plan (schema
 v4) so warm replays skip the pass (``autotune_passes == 0``).
 
 Counters: ``kernel_dispatch_hits`` / ``kernel_dispatch_misses`` /
-``kernel_dispatch_computed_mask`` in ``core.stats`` make dispatch coverage
-observable in serve logs.
+``kernel_dispatch_errors`` / ``kernel_dispatch_computed_mask`` in
+``core.stats`` make dispatch coverage observable in serve logs; an error is
+a matcher or validation that raised, whose body fell back to scan codegen.
 """
 from __future__ import annotations
 
@@ -851,8 +852,26 @@ def _try_swiglu(ctx: _BodyCtx, i_dg3: int) -> Optional[Match]:
     if ge is None or ue is None:
         return None
     wg_slice = wu_slice = None
-    if ge.primitive.name == "slice" and ue.primitive.name == "slice":
-        # fused w_in form: u, g = split(x @ w_in, 2, axis=-1)
+    if ge.primitive.name == "split" and ge is ue:
+        # fused w_in form, one split eqn: u, g = split(x @ w_in, 2, axis=-1)
+        rank = len(ge.invars[0].aval.shape)
+        if int(ge.params["axis"]) % rank != rank - 1:
+            return None
+        i_h0, h0 = _producer_eqn(ctx, ge.invars[0])
+        if h0 is None or h0.primitive.name != "dot_general" \
+                or not _plain_matmul(h0):
+            return None
+        x_var, w_in = h0.invars
+        bounds = np.cumsum([0] + [int(n) for n in ge.params["sizes"]])
+        gi = list(ge.outvars).index(g_var)
+        ui = list(ge.outvars).index(u_var)
+        wg_slice = (int(bounds[gi]), int(bounds[gi + 1]))
+        wu_slice = (int(bounds[ui]), int(bounds[ui + 1]))
+        wg_var = wu_var = w_in
+        interior |= {i_g, i_h0}
+    elif ge.primitive.name == "slice" and ue.primitive.name == "slice":
+        # fused w_in form written as two slices:
+        # u, g = h[..., :f], h[..., f:] with h = x @ w_in
         i_h0g, h0g = _producer_eqn(ctx, ge.invars[0])
         i_h0u, h0u = _producer_eqn(ctx, ue.invars[0])
         if h0g is not h0u or h0g is None:
@@ -927,7 +946,8 @@ def _try_swiglu(ctx: _BodyCtx, i_dg3: int) -> Optional[Match]:
     s_full = _prod(x_var.aval.shape[:-1])
     tile = 4 * (_BLOCK * _BLOCK_F + 2 * _BLOCK * max(d_sz, 1))
     meta = {
-        "site": {"kind": "swiglu", "s": s_full, "d": d_sz, "f": f_sz},
+        "site": {"kind": "swiglu", "s": s_full, "d": d_sz, "f": f_sz,
+                 "itemsize": int(np.dtype(x_var.aval.dtype).itemsize)},
         # s is the flattened leading-dim product: it scales by c/extent of
         # the chunked dim rather than collapsing to c
         "chunk_adjust": {"s": int(x_var.aval.shape[dx])},
@@ -1044,8 +1064,11 @@ def dispatch_node(
         matches = match_body(ctx, mask_mode)
     except Exception:
         # dispatch must never break a compilable plan: an exotic body that
-        # trips the matcher falls back to generic scan codegen
-        matches = []
+        # trips the matcher falls back to generic scan codegen, counted
+        # apart from bodies that simply do not match
+        refresh_node(node)
+        stats.bump("kernel_dispatch_errors")
+        return 0
     if not matches:
         refresh_node(node)  # drop any dispatch-aware body_peak cap
         stats.bump("kernel_dispatch_misses")
@@ -1094,7 +1117,7 @@ def dispatch_node(
         node.params["captured"] = saved[2]
         node.invars = saved[3]
         refresh_node(node)
-        stats.bump("kernel_dispatch_misses")
+        stats.bump("kernel_dispatch_errors")
         return 0
     refresh_node(node)
     stats.bump("kernel_dispatch_hits", len(records))
@@ -1183,6 +1206,7 @@ def dispatch_graph(
             try:
                 ms = match_body(_ctx_from_node(node, g, outer), mask_mode)
             except Exception:
+                stats.bump("kernel_dispatch_errors")
                 ms = []
             sites.extend(_node_sites(node, ms))
         if sites:
@@ -1220,6 +1244,7 @@ def annotate_candidates(
                 _ctx_from_candidate(g, cand, outer), mask_mode
             )
         except Exception:
+            stats.bump("kernel_dispatch_errors")
             continue
         if matches:
             cand.kernel_tile_bytes = sum(m.tile_bytes for m in matches)

@@ -461,7 +461,11 @@ class Traced:
                     cur, self.flat_args, weight_argnums=self.weight_flat
                 )
                 prof = estimate_memory(g, mesh_spec=config.mesh_spec)
-        else:  # nothing chunked: the baseline graph is the program
+            if prof.peak_bytes > self.baseline_peak:
+                # the rewrite-time estimate was optimistic and the emitted
+                # program would raise the peak: keep the unchunked program
+                lowered, records, pstages, tuning = self.graph, [], [], None
+        if not pstages:  # nothing chunked: the baseline graph is the program
             cur, g, prof = self.flat_fn, self.graph, self.profile
         meta = {
             "io_bytes": prof.io_bytes,
@@ -770,8 +774,8 @@ class CompiledFunction:
     def report(self) -> str:
         return self.result.report()
 
-    def xla_cache_size(self) -> Optional[int]:
-        """Number of XLA executables behind the lazy jit (None if unknown).
+    def xla_cache_size(self) -> int:
+        """Number of XLA executables behind the lazy jit.
 
         The one-executable-per-bucket invariant is stated in these terms: a
         canonical bucket executable's cache size stays 1 no matter how many
@@ -780,10 +784,7 @@ class CompiledFunction:
         """
         if self._jitted is None:
             return 0
-        try:
-            return int(self._jitted._cache_size())
-        except AttributeError:  # older/newer jax without the private probe
-            return None
+        return int(self._jitted._cache_size())
 
     def _in_shardings(self):
         """Arg-tree of ``NamedSharding``s when a mesh is configured.

@@ -47,6 +47,9 @@ _PIPELINE_COUNTERS = (
     # swapped for fused kernels vs bodies examined and left as scan codegen.
     "kernel_dispatch_hits",
     "kernel_dispatch_misses",
+    # matcher or body validation raised: the body fell back to scan codegen
+    # (a miss is a body the matchers examined and did not recognize)
+    "kernel_dispatch_errors",
     # attention dispatches whose mask classified as causal/sliding-window and
     # lowered onto the position-computed kernel (no (Sq,Skv) bool array ever
     # exists); the remainder of kernel_dispatch_hits stream a boolean mask
@@ -60,6 +63,8 @@ _PIPELINE_COUNTERS = (
     "autotune_passes",
     "autotune_cache_hits",
     "autotune_trials",
+    # measured-mode candidates that failed to compile or run on the chip
+    "autotune_failures",
     "plan_cache_hits",
     "plan_cache_misses",
     "plan_replays",

@@ -35,7 +35,14 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..core import stats
 from ..obs.tracing import span
-from .tiling import legal_block, legal_candidates
+from .tiling import (
+    LANE,
+    VMEM_BUDGET,
+    ffn_vmem_bytes,
+    legal_block,
+    legal_candidates,
+    paged_vmem_bytes,
+)
 
 # candidate grids (rounded to legal blocks per site before costing)
 _ATTN_BQ = (64, 128, 256)
@@ -46,20 +53,18 @@ _DEPTHS = (2, 4)
 _PAGES_PER_STEP = (1, 2, 4)
 
 TIMING_REPS = 3
-# Mosaic leaves headroom for its own spills; don't plan tiles into the last
-# quarter of VMEM (16 MiB/core on current TPUs)
-VMEM_BUDGET = int(16 * 1024 * 1024 * 0.75)
 # analytic model: relative cost of one grid step's fixed overhead, in
 # "streamed byte" units — calibrated only to break ties toward fewer steps
 # when the working sets are comparable
 _STEP_OVERHEAD_BYTES = 4096
 
 
-def _stream_block(size: int, block: int, depth: int) -> int:
+def _stream_block(size: int, block: int, depth: int, *,
+                  align: int = 8) -> int:
     """Realized streamed-axis block at a buffer depth (mirrors ops)."""
     if depth >= 4:
         block = max(block // 2, 1)
-    return legal_block(size, block)
+    return legal_block(size, block, align=align)
 
 
 @dataclass(frozen=True)
@@ -154,7 +159,7 @@ def _swiglu_candidates(site: Dict[str, Any]) -> List[Dict[str, int]]:
     s, f = int(site["s"]), int(site["f"])
     out = []
     for bs in legal_candidates(s, _FFN_BS):
-        for bf in legal_candidates(f, _FFN_BF):
+        for bf in legal_candidates(f, _FFN_BF, align=LANE):
             for depth in _DEPTHS:
                 out.append({"block_s": bs, "block_f": bf,
                             "buffer_depth": depth})
@@ -166,14 +171,12 @@ def _swiglu_cost(site: Dict[str, Any], cand: Dict[str, int]) -> float:
     d = int(site.get("d", 256))
     depth = cand["buffer_depth"]
     bs = legal_block(s, cand["block_s"])
-    bf = _stream_block(f, cand["block_f"], depth)
-    # x block + 3 double-buffered weight stream blocks + accumulator + the
-    # (bs, bf) gate/up tiles
-    vmem = 4 * (bs * d + 2 * 3 * d * bf + bs * d + 2 * bs * bf)
-    if vmem > VMEM_BUDGET:
+    bf = _stream_block(f, cand["block_f"], depth, align=LANE)
+    itemsize = int(site.get("itemsize", 4))
+    if ffn_vmem_bytes(bs, bf, d, itemsize) > VMEM_BUDGET:
         return float("inf")
     steps = -(-s // bs) * -(-f // bf)
-    stream_bytes = steps * 4 * 3 * d * bf          # wg + wu + wd per step
+    stream_bytes = steps * itemsize * 3 * d * bf   # wg + wu + wd per step
     return steps * _STEP_OVERHEAD_BYTES + stream_bytes / depth
 
 
@@ -198,9 +201,8 @@ def _paged_cost(site: Dict[str, Any], cand: Dict[str, int]) -> float:
     n_seqs = int(site.get("n_seqs", 1))
     pps = cand["pages_per_step"]
     page_bytes = 4 * page_size * 2 * kv * hd
-    # pps pages of KV in flight (double-buffered) + q block + accumulator
-    vmem = 2 * pps * page_bytes + 4 * q_max * h * hd * 2
-    if vmem > VMEM_BUDGET:
+    rows = min(q_max, 128) * max(h // kv, 1)
+    if paged_vmem_bytes(rows, kv, hd, page_size, pps, 4) > VMEM_BUDGET:
         return float("inf")
     steps = n_seqs * -(-max_pages // pps)
     stream_bytes = steps * pps * page_bytes
@@ -213,6 +215,30 @@ def _paged_cost(site: Dict[str, Any], cand: Dict[str, int]) -> float:
 
 def _measured_cost(kind: str, site: Dict[str, Any],
                    cand: Dict[str, int]) -> float:
+    """Best-of-``TIMING_REPS`` wall time of one candidate on the device.
+
+    Tuning can be reached while an outer jit is tracing (a model's block
+    compiles lazily inside its layer scan).  There, the inputs are made
+    eagerly and the candidate runs as an ahead-of-time compiled
+    executable, so the timings measure the kernel, not the creation of
+    tracers.
+    """
+    import jax
+
+    with jax.ensure_compile_time_eval():
+        fn, args = _candidate_run(kind, site, cand)
+    run = jax.jit(fn).lower(*args).compile()
+    jax.block_until_ready(run(*args))  # first run outside the timed region
+    best = float("inf")
+    for _ in range(TIMING_REPS):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*args))
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def _candidate_run(kind: str, site: Dict[str, Any], cand: Dict[str, int]):
+    """``(fn, args)``: ``kind``'s kernel with ``cand`` on zeros at ``site``."""
     import jax.numpy as jnp
 
     from . import ops
@@ -222,14 +248,17 @@ def _measured_cost(kind: str, site: Dict[str, Any],
         sq, skv, hd = int(site["sq"]), int(site["skv"]), int(site.get("hd", 64))
         q = jnp.zeros((1, sq, n, hd), jnp.float32)
         k = jnp.zeros((1, skv, n, hd), jnp.float32)
-        run = lambda: ops.attention(q, k, k, causal=True, **cand)
-    elif kind == "swiglu":
+        return (lambda q, k: ops.attention(q, k, k, causal=True, **cand),
+                (q, k))
+    if kind == "swiglu":
         s, d, f = int(site["s"]), int(site.get("d", 256)), int(site["f"])
-        x = jnp.zeros((s, d), jnp.float32)
-        wg = jnp.zeros((d, f), jnp.float32)
-        wd = jnp.zeros((f, d), jnp.float32)
-        run = lambda: ops.swiglu_ffn(x, wg, wg, wd, **cand)
-    elif kind == "paged":
+        dt = jnp.bfloat16 if int(site.get("itemsize", 4)) == 2 else jnp.float32
+        x = jnp.zeros((s, d), dt)
+        wg = jnp.zeros((d, f), dt)
+        wd = jnp.zeros((f, d), dt)
+        return (lambda x, wg, wd: ops.swiglu_ffn(x, wg, wg, wd, **cand),
+                (x, wg, wd))
+    if kind == "paged":
         from .paged_attention import paged_attention_blocked
 
         ps = int(site.get("page_size", 16))
@@ -243,23 +272,10 @@ def _measured_cost(kind: str, site: Dict[str, Any],
         pages = jnp.zeros((max(mp, 1), ps, 2 * kvh, hd), jnp.float32)
         pt = jnp.zeros((n_seqs, mp), jnp.int32)
         lens = jnp.full((n_seqs,), qm, jnp.int32)
-        run = lambda: paged_attention_blocked(
-            q, pages, pt, lens, lens, **cand)
-    else:  # pragma: no cover - unknown kinds are filtered by the caller
-        return float("inf")
-
-    try:
-        run()  # compile outside the timed region
-        best = float("inf")
-        for _ in range(TIMING_REPS):
-            t0 = time.perf_counter()
-            import jax
-
-            jax.block_until_ready(run())
-            best = min(best, time.perf_counter() - t0)
-        return best
-    except Exception:
-        return float("inf")
+        return (lambda q, pages, pt, lens: paged_attention_blocked(
+            q, pages, pt, lens, lens, interpret=ops.interpret_default(),
+            **cand), (q, pages, pt, lens))
+    raise ValueError(f"unknown kernel kind {kind!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -331,16 +347,32 @@ def tune_sites(sites: Sequence[Dict[str, Any]], *,
             if not cands:
                 cands = cand_lists[0]
             best, best_cost = None, float("inf")
+            failed = []
             for cand in cands:
+                trials += 1
                 if mode == "measured":
-                    cost = sum(
-                        _measured_cost(kind, s, cand) for s in kind_sites
-                    )
+                    try:
+                        cost = sum(
+                            _measured_cost(kind, s, cand) for s in kind_sites
+                        )
+                    except Exception as e:  # a candidate the chip refuses
+                        failed.append((cand, f"{type(e).__name__}: {e}"))
+                        continue
                 else:
                     cost = sum(analytic(s, cand) for s in kind_sites)
-                trials += 1
                 if cost < best_cost:
                     best, best_cost = cand, cost
+            if failed:
+                stats.bump("autotune_failures", len(failed))
+                print(f"[autotune] {kind}: {len(failed)} of {len(cands)}"
+                      " candidates failed to compile or run")
+                for cand, err in failed:
+                    print(f"[autotune]   {cand}: {err.splitlines()[0][:200]}")
+                if best is None:
+                    raise RuntimeError(
+                        f"autotune: no {kind} candidate compiled for"
+                        f" {kind_sites}; first error: {failed[0][1][:500]}"
+                    )
             if best is not None and best_cost != float("inf"):
                 winners[kind] = best
     stats.bump("autotune_trials", trials)

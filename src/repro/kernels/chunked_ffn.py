@@ -8,17 +8,18 @@ activations ever exists in VMEM, accumulating partial down-projections into a
 VMEM scratch across the f-blocks.
 
 Grid: (s_blocks, f_blocks) — f innermost, accumulator carried in scratch.
+The d_ff block is the lane dim of the gate/up weight blocks, so it is a
+multiple of 128 (or the whole d_ff), and it shrinks until the step fits the
+VMEM budget (:func:`repro.kernels.tiling.ffn_vmem_bytes`).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .tiling import legal_block
+from .tiling import LANE, VMEM_BUDGET, ffn_vmem_bytes, legal_block
 
 
 def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
@@ -40,6 +41,17 @@ def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def ffn_blocks(S: int, d: int, f: int, itemsize: int,
+               block_s: int = 128, block_f: int = 512):
+    """Legal ``(bs, bf)`` tiles at most the wanted ones that fit VMEM."""
+    bs = legal_block(S, block_s)
+    bf = legal_block(
+        f, block_f, align=LANE,
+        fits=lambda b: ffn_vmem_bytes(bs, b, d, itemsize) <= VMEM_BUDGET,
+    )
+    return bs, bf
+
+
 def chunked_ffn(
     x, w_gate, w_up, w_down, *,
     block_s: int = 128,
@@ -49,8 +61,8 @@ def chunked_ffn(
     """x: (S, d); w_gate/w_up: (d, f); w_down: (f, d) -> (S, d)."""
     S, d = x.shape
     f = w_gate.shape[1]
-    bs = legal_block(S, block_s)
-    bf = legal_block(f, block_f)
+    bs, bf = ffn_blocks(S, d, f, jnp.dtype(w_gate.dtype).itemsize,
+                        block_s, block_f)
     grid = (S // bs, f // bf)
     return pl.pallas_call(
         _ffn_kernel,

@@ -1,12 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (``interpret=True``
-executes the kernel body in Python for correctness); on TPU the same call
-compiles to Mosaic.  ``INTERPRET`` flips automatically from the backend,
-and the ``AUTOCHUNK_PALLAS_INTERPRET`` env var overrides the detection
-("1" forces interpret mode — the CPU CI matrix sets this so kernel
-equivalence tests run deterministically instead of skipping; "0" forces
-compiled Mosaic, for the ``tpu``-marked true-hardware tests).
+On a CPU the kernels run in interpret mode (``interpret=True`` executes
+the kernel body in Python for correctness); on TPU the same call compiles
+to Mosaic.  ``INTERPRET`` follows the backend, resolved on first use (not
+at import, so importing this module does not initialize a backend), and
+the ``AUTOCHUNK_PALLAS_INTERPRET`` env var overrides the detection ("1"
+forces interpret mode — the CPU test suites set this; "0" forces compiled
+Mosaic).  A chip run checks that ``INTERPRET`` is false: a kernel in
+interpret mode on a TPU is a fault, not a fallback.
 GQA inputs are expanded to full heads before the attention kernel (the
 kernel itself is head-uniform).
 """
@@ -60,13 +61,16 @@ def set_interpret(value: "bool | None") -> bool:
     back to lazy env/backend resolution.  Returns the now-active mode.
     Call it before the first use of a kernel wrapper — already-traced jit
     entries keep the mode they were traced with."""
-    global _INTERPRET_RESOLVED, INTERPRET
+    global _INTERPRET_RESOLVED
     _INTERPRET_RESOLVED = value
-    INTERPRET = interpret_default()
-    return INTERPRET
+    return interpret_default()
 
 
-INTERPRET = interpret_default()
+def __getattr__(name):
+    # ``ops.INTERPRET``: the resolved mode, computed on first access
+    if name == "INTERPRET":
+        return interpret_default()
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def _stream_block(size: int, block: int, buffer_depth: int) -> int:
@@ -102,19 +106,18 @@ def attention(q, k, v, *, causal=True, window=None, block_q=128, block_kv=128,
     bkv = _stream_block(k.shape[1], block_kv, buffer_depth)
     return _attn(
         q, k, v, causal=causal, window=window,
-        block_q=bq, block_kv=bkv, interpret=INTERPRET,
+        block_q=bq, block_kv=bkv, interpret=interpret_default(),
     )
 
 
 @partial(jax.jit, static_argnames=("block_s", "block_f", "buffer_depth"))
 def swiglu_ffn(x, w_gate, w_up, w_down, *, block_s=128, block_f=512,
                buffer_depth=2):
-    S = x.shape[0]
-    f = w_gate.shape[1]
-    bs = legal_block(S, block_s)
-    bf = _stream_block(f, block_f, buffer_depth)
-    return _ffn(x, w_gate, w_up, w_down, block_s=bs, block_f=bf,
-                interpret=INTERPRET)
+    # chunked_ffn legalizes the tiles and shrinks the d_ff block to fit VMEM
+    if buffer_depth >= 4:
+        block_f = max(block_f // 2, 1)
+    return _ffn(x, w_gate, w_up, w_down, block_s=block_s, block_f=block_f,
+                interpret=interpret_default())
 
 
 @partial(jax.jit, static_argnames=("scale", "block_q", "block_kv",
@@ -131,7 +134,7 @@ def masked_attention(q, k, v, mask, *, scale, block_q=128, block_kv=128,
     bkv = _stream_block(k.shape[1], block_kv, buffer_depth)
     return _masked_attn(
         q, k, v, mask, scale=scale,
-        block_q=bq, block_kv=bkv, interpret=INTERPRET,
+        block_q=bq, block_kv=bkv, interpret=interpret_default(),
     )
 
 
@@ -152,7 +155,7 @@ def computed_attention(q, k, v, q_offset=None, *, scale, causal=True,
     bkv = _stream_block(k.shape[1], block_kv, buffer_depth)
     return _computed_attn(
         q, k, v, scale=scale, causal=causal, window=window,
-        q_offset=q_offset, block_q=bq, block_kv=bkv, interpret=INTERPRET,
+        q_offset=q_offset, block_q=bq, block_kv=bkv, interpret=interpret_default(),
     )
 
 
@@ -186,7 +189,7 @@ def paged_attention(q, kv_pages, page_table, cu_q_lens, cu_kv_lens, *,
     qb = jnp.take(q, jnp.clip(idx, 0, T - 1), axis=0)        # (S, q_max, H, hd)
     out_b = _paged_attn(
         qb, kv_pages, page_table, q_lens, kv_lens,
-        scale=scale, pages_per_step=pages_per_step, interpret=INTERPRET,
+        scale=scale, pages_per_step=pages_per_step, interpret=interpret_default(),
     )
     # scatter back to the flat layout; padded rows land in a dump slot
     flat_idx = jnp.where(valid, idx, T).reshape(-1)
@@ -202,7 +205,7 @@ def ssd(x, dt, A, B, C, *, chunk=128):
     q = min(chunk, s)
     while s % q:
         q //= 2
-    return _ssd(x, dt, A, B, C, chunk=max(q, 1), interpret=INTERPRET)
+    return _ssd(x, dt, A, B, C, chunk=max(q, 1), interpret=interpret_default())
 
 
 @partial(jax.jit, static_argnames=("chunk",))
@@ -211,4 +214,4 @@ def rglru(a, b, *, chunk=256):
     q = min(chunk, s)
     while s % q:
         q //= 2
-    return _rglru(a, b, chunk=max(q, 1), interpret=INTERPRET)
+    return _rglru(a, b, chunk=max(q, 1), interpret=interpret_default())

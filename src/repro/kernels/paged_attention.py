@@ -17,9 +17,10 @@ dense per-sequence cache ever happens in HBM:
   sits at absolute position ``kv_lens[s] - q_lens[s] + i`` and attends to
   positions ``<=`` its own.
 
-Grid: ``(S, max_pages)`` with the page index innermost, so the online-softmax
-accumulator carries across a sequence's pages in VMEM scratch.  Pages past
-``ceil(kv_len / page_size)`` are skipped (``pl.when``); their page-table
+Grid: ``(S, q_blocks, max_pages)`` with the page index innermost, so the
+online-softmax accumulator carries across a sequence's pages in VMEM
+scratch.  Pages past ``ceil(kv_len / page_size)`` and pages wholly after a
+query block's last position are skipped (``pl.when``); their page-table
 entries are clamped to a valid physical page so the prefetch never reads out
 of bounds.
 
@@ -35,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import VMEM_BUDGET, legal_block, paged_vmem_bytes
 
 NEG_INF = -1e30
 
@@ -61,7 +64,7 @@ def _paged_attn_kernel(
     pt_ref, ql_ref, kl_ref,
     # tensor refs: q, pages_per_step kv page blocks, output, then scratch
     q_ref, *rest,
-    scale: float, page_size: int, q_max: int, n_q_heads: int, n_kv_heads: int,
+    scale: float, page_size: int, block_q: int, group: int, n_kv_heads: int,
     pages_per_step: int,
 ):
     kv_refs = rest[:pages_per_step]
@@ -69,8 +72,9 @@ def _paged_attn_kernel(
     acc_ref, m_ref, l_ref = rest[pages_per_step + 1:]
 
     s = pl.program_id(0)
-    ki = pl.program_id(1)
-    nk = pl.num_programs(1)
+    qb = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -80,58 +84,55 @@ def _paged_attn_kernel(
 
     q_len = ql_ref[s]
     kv_len = kl_ref[s]
+    # absolute position of this query block's first token
+    q0 = kv_len - q_len + qb * block_q
+    rows = block_q * group
+    live_q = qb * block_q < q_len
 
     # unrolled over the step's pages: each logical page gets its own guarded
     # online-softmax update, so one grid step drains ``pages_per_step``
     # already-prefetched page DMAs (the autotuner picks the step width)
     for t in range(pages_per_step):
         kv_ref = kv_refs[t]
-        logical = ki * pages_per_step + t
+        start = (ki * pages_per_step + t) * page_size
 
-        @pl.when(logical * page_size < kv_len)
-        def _accumulate(kv_ref=kv_ref, logical=logical):
-            G = n_q_heads // n_kv_heads
-            hd = q_ref.shape[-1]
-            q = q_ref[0].astype(jnp.float32)                # (q_max, H, hd)
-            k, v = split_kv(kv_ref[0].astype(jnp.float32))  # (ps, Kv, hd)
-
-            qg = q.reshape(q_max, n_kv_heads, G, hd)
-            # (q_max, Kv, G, ps) logits for this page
-            logits = jnp.einsum("qkgd,pkd->qkgp", qg, k) * scale
-            logits = logits.reshape(q_max, n_q_heads, page_size)
-
-            kpos = logical * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (q_max, page_size), 1
-            )
-            qpos = (kv_len - q_len) + jax.lax.broadcasted_iota(
-                jnp.int32, (q_max, page_size), 0
-            )
-            mask = (kpos <= qpos) & (kpos < kv_len)
-            logits = jnp.where(mask[:, None, :], logits, NEG_INF)
-
-            m_prev = m_ref[...]                              # (q_max, H)
-            m_cur = jnp.max(logits, axis=-1)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(logits - m_new[..., None])           # (q_max, H, ps)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1)
-            pg = p.reshape(q_max, n_kv_heads, G, page_size)
-            pv = jnp.einsum("qkgp,pkd->qkgd", pg, v).reshape(
-                q_max, n_q_heads, hd
-            )
-            acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
-            m_ref[...] = m_new
+        # skip pages past the context and pages wholly after the block's
+        # last query (causal)
+        @pl.when(live_q & (start < kv_len) & (start <= q0 + block_q - 1))
+        def _accumulate(kv_ref=kv_ref, start=start):
+            # row r of a head holds query r // group, so the causal test
+            # kpos <= q0 + r // group is r >= group * (kpos - q0)
+            kpos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page_size), 1)
+            r = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
+            mask = (r >= group * (kpos - q0)) & (kpos < kv_len)
+            for h in range(n_kv_heads):
+                q = q_ref[0, 0, h].astype(jnp.float32)             # (rows, hd)
+                k = kv_ref[0, :, 2 * h, :].astype(jnp.float32)     # (ps, hd)
+                v = kv_ref[0, :, 2 * h + 1, :].astype(jnp.float32)
+                logits = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ()))) * scale        # (rows, ps)
+                logits = jnp.where(mask, logits, NEG_INF)
+                m_prev = m_ref[h]                                  # (rows, 1)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(logits, axis=-1, keepdims=True))
+                p = jnp.exp(logits - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * alpha + p @ v
+                m_ref[h] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        o_ref[0] = out.astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_blocked(
     q, kv_pages, page_table, q_lens, kv_lens, *,
     scale: float | None = None,
     pages_per_step: int = 1,
+    block_q: int = 128,
     interpret: bool = False,
 ):
     """Ragged paged attention over per-sequence-blocked queries.
@@ -146,6 +147,14 @@ def paged_attention_blocked(
     total context *including* its own q tokens, which must already be
     written into the pool.  Returns (S, q_max, H, hd).
 
+    The GQA grouping happens outside the kernel: q is laid out per KV head
+    as ``(S, q_blocks, Kv, block_q * G, hd)``, so inside the kernel every
+    head is one 2-D ``(block_q * G, hd) x (page_size, hd)`` dot against its
+    K and V rows of the page.  The query axis is blocked in the grid, and
+    ``block_q`` shrinks until a grid step fits the VMEM budget
+    (:func:`repro.kernels.tiling.paged_vmem_bytes`), so prefill chunks of
+    any width keep VMEM bounded.
+
     ``pages_per_step`` widens the inner grid step: the kernel takes that
     many page-table-indexed KV operands per step (each its own prefetched
     DMA block) and drains them in an unrolled guarded loop — more page
@@ -155,12 +164,24 @@ def paged_attention_blocked(
     S, q_max, H, hd = q.shape
     P, page_size, two_kv, _ = kv_pages.shape
     Kv = two_kv // 2
-    assert H % Kv == 0, (H, Kv)
+    if H % Kv:
+        raise ValueError(f"{H} query heads do not group over {Kv} KV heads")
+    G = H // Kv
     if scale is None:
         scale = 1.0 / (hd ** 0.5)
     max_pages = page_table.shape[1]
     pages_per_step = max(1, min(int(pages_per_step), max_pages))
     n_steps = -(-max_pages // pages_per_step)  # ceil
+    itemsize = jnp.dtype(q.dtype).itemsize
+    # the q rows are the whole block axis, so any divisor of q_max is legal
+    bq = legal_block(
+        q_max, block_q, align=1,
+        fits=lambda b: paged_vmem_bytes(
+            b * G, Kv, hd, page_size, pages_per_step, itemsize
+        ) <= VMEM_BUDGET,
+    )
+    nqb = q_max // bq
+    rows = bq * G
 
     # inactive page-table entries may be uninitialized: clamp so the
     # prefetched index map always names a physical page
@@ -168,10 +189,15 @@ def paged_attention_blocked(
     q_lens = q_lens.astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
 
+    # (S, q_max, H, hd) -> (S, nqb, Kv, bq * G, hd); query head kv * G + g
+    # at block position i sits in row i * G + g of KV head kv
+    qg = q.reshape(S, nqb, bq, Kv, G, hd).transpose(0, 1, 3, 2, 4, 5)
+    qg = qg.reshape(S, nqb, Kv, rows, hd)
+
     kernel = functools.partial(
         _paged_attn_kernel,
-        scale=float(scale), page_size=page_size, q_max=q_max,
-        n_q_heads=H, n_kv_heads=Kv, pages_per_step=pages_per_step,
+        scale=float(scale), page_size=page_size, block_q=bq, group=G,
+        n_kv_heads=Kv, pages_per_step=pages_per_step,
     )
 
     def _kv_spec(t):
@@ -179,31 +205,31 @@ def paged_attention_blocked(
         # non-dividing step width) — those reads are skipped in the kernel
         return pl.BlockSpec(
             (1, page_size, two_kv, hd),
-            lambda s, ki, pt, ql, kl, t=t: (
+            lambda s, qb, ki, pt, ql, kl, t=t: (
                 pt[s, jnp.minimum(ki * pages_per_step + t, max_pages - 1)],
                 0, 0, 0,
             ),
         )
 
+    q_spec = pl.BlockSpec(
+        (1, 1, Kv, rows, hd), lambda s, qb, ki, pt, ql, kl: (s, qb, 0, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, n_steps),
-        in_specs=[
-            pl.BlockSpec((1, q_max, H, hd), lambda s, ki, pt, ql, kl: (s, 0, 0, 0)),
-            *[_kv_spec(t) for t in range(pages_per_step)],
-        ],
-        out_specs=pl.BlockSpec(
-            (1, q_max, H, hd), lambda s, ki, pt, ql, kl: (s, 0, 0, 0)
-        ),
+        grid=(S, nqb, n_steps),
+        in_specs=[q_spec, *[_kv_spec(t) for t in range(pages_per_step)]],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((q_max, H, hd), jnp.float32),
-            pltpu.VMEM((q_max, H), jnp.float32),
-            pltpu.VMEM((q_max, H), jnp.float32),
+            pltpu.VMEM((Kv, rows, hd), jnp.float32),
+            pltpu.VMEM((Kv, rows, 1), jnp.float32),
+            pltpu.VMEM((Kv, rows, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, q_max, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
-    )(page_table, q_lens, kv_lens, q, *([kv_pages] * pages_per_step))
+    )(page_table, q_lens, kv_lens, qg, *([kv_pages] * pages_per_step))
+    out = out.reshape(S, nqb, Kv, bq, G, hd).transpose(0, 1, 3, 2, 4, 5)
+    return out.reshape(S, q_max, H, hd)

@@ -1,31 +1,36 @@
-"""Shared tile-legality rules for the Pallas kernels and the autotuner.
+"""Shared tile-legality and VMEM rules for the Pallas kernels and the autotuner.
 
-TPU tiling constraints (Mosaic): the last dim maps onto 128 lanes and the
-second-to-last onto 8 sublanes (f32; bf16 wants 16 but Mosaic pads), so
-sequence-axis block sizes should be sublane multiples.  Pallas additionally
-requires a block to divide the axis it tiles (the grid is ``size // block``
-with per-block index maps; a non-dividing block would read out of bounds).
+TPU tiling constraints (Mosaic): the last two dims of every block must be
+divisible by 8 (sublanes) and 128 (lanes) respectively, or equal the whole
+axis.  Pallas additionally requires a block to divide the axis it tiles
+(the grid is ``size // block`` with per-block index maps; a non-dividing
+block would read out of bounds).
 
-Both the hand-tuned kernel entry points (``chunked_attention.py`` /
-``chunked_ffn.py``) and the autotune candidate grid
-(``kernels.autotune``) go through :func:`legal_block`, so "legal tile" is
-one definition — ``bq = min(block_q, Sq)`` clamping that used to produce
-non-dividing (AssertionError) or lane-misaligned tiles is gone.
+Every kernel also has to fit its blocks, their DMA double buffers and its
+scratch into the scoped VMEM limit (16 MiB by default on v5e).  The VMEM
+models below are the one estimate of that footprint: the kernels' default
+tile choice and the autotuner's candidate filter both read them, so a
+default tile is never one the tuner would reject, and vice versa.
 """
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import Callable, List, Optional, Sequence
 
-# f32 sublane count — the alignment unit for sequence-axis block dims.
+# f32 sublane count — the alignment unit for second-to-last block dims.
 SUBLANE = 8
+# lane count — the alignment unit for last block dims.
+LANE = 128
+
+# Mosaic leaves headroom for its own spills; tiles are planned into the
+# first three quarters of the 16 MiB scoped VMEM limit.
+VMEM_BUDGET = int(16 * 1024 * 1024 * 0.75)
 
 
 def is_legal_block(total: int, block: int, *, align: int = SUBLANE) -> bool:
     """True when ``block`` legally tiles an axis of extent ``total``.
 
     Legal means: divides ``total`` (Pallas grid requirement) AND is either
-    sublane-aligned or the whole axis (a single block of odd extent is as
-    aligned as that axis can get — Mosaic pads it internally).
+    ``align``-aligned or the whole axis.
     """
     if not 0 < block <= total:
         return False
@@ -34,25 +39,27 @@ def is_legal_block(total: int, block: int, *, align: int = SUBLANE) -> bool:
     return block % align == 0 or block == total
 
 
-def legal_block(total: int, want: int, *, align: int = SUBLANE) -> int:
+def legal_block(
+    total: int,
+    want: int,
+    *,
+    align: int = SUBLANE,
+    fits: Optional[Callable[[int], bool]] = None,
+) -> int:
     """Largest legal block <= ``want`` for an axis of extent ``total``.
 
-    Prefers the largest aligned divisor; when no divisor of ``total`` up to
-    ``want`` is a multiple of ``align`` (odd extents, tiny axes) it falls
-    back to the largest divisor, bottoming out at the full axis -- never an
-    illegal (non-dividing) tile, unlike ``min()``-then-assert clamping.
+    Returns the largest ``align``-aligned divisor of ``total`` that is at
+    most ``want`` (and, with ``fits``, for which ``fits(block)`` holds).
+    When there is none — odd extents, tiny axes — the block is the whole
+    axis, which Mosaic always accepts.  A smaller unaligned divisor is
+    never returned: Mosaic refuses it.
     """
     total = int(total)
     want = max(1, min(int(want), total))
-    best = 0
-    for b in range(want, 0, -1):
-        if total % b:
-            continue
-        if best == 0:
-            best = b  # largest divisor <= want (alignment fallback)
-        if b % align == 0:
+    for b in range(want - want % align, 0, -align):
+        if total % b == 0 and (fits is None or fits(b)):
             return b
-    return best or total
+    return total
 
 
 def legal_candidates(
@@ -70,3 +77,31 @@ def legal_candidates(
         if b not in out:
             out.append(b)
     return sorted(out)
+
+
+def ffn_vmem_bytes(bs: int, bf: int, d: int, itemsize: int) -> int:
+    """Scoped VMEM of one ``chunked_ffn`` grid step.
+
+    Double-buffered x and output blocks ``(bs, d)``, double-buffered gate,
+    up and down weight blocks ``(d, bf)``, the f32 accumulator ``(bs, d)``
+    and the f32 gate/up tiles ``(bs, bf)``.
+    """
+    io = 2 * (2 * bs * d + 3 * d * bf) * itemsize
+    return io + 4 * (bs * d + 2 * bs * bf)
+
+
+def paged_vmem_bytes(rows: int, n_kv: int, hd: int, page_size: int,
+                     pages_per_step: int, itemsize: int) -> int:
+    """Scoped VMEM of one ``paged_attention_blocked`` grid step.
+
+    ``rows`` is query positions per block times the GQA group.  Counts the
+    double-buffered query and output blocks ``(n_kv, rows, hd)``, the f32
+    accumulator, the lane-padded f32 running max and sum, the
+    double-buffered page blocks, and one head's f32 logits tile.
+    """
+    qo = 2 * 2 * n_kv * rows * hd * itemsize
+    acc = 4 * n_kv * rows * hd
+    ml = 2 * 4 * n_kv * rows * LANE
+    pages = 2 * pages_per_step * page_size * 2 * n_kv * hd * itemsize
+    tile = 4 * rows * (max(page_size, LANE) + 2 * hd)
+    return qo + acc + ml + pages + tile

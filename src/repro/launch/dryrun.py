@@ -1,10 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ^ MUST precede any jax import: jax locks the device count on first init.
-# The 512 placeholder host devices exist ONLY for this dry-run; smoke tests
-# and benchmarks see the real single CPU device.
-
 """Multi-pod dry-run: prove every (arch x input-shape x mesh) combination
 lowers, compiles, and what it costs.
 
@@ -15,10 +8,12 @@ production rules, runs ``.lower().compile()``, and records:
   * memory_analysis()  — per-device argument/output/temp bytes (fits or not),
   * cost_analysis()    — per-device FLOPs and HBM bytes,
   * collective bytes   — parsed from the post-SPMD optimized HLO,
-  * the derived three-term roofline (see benchmarks/roofline.py).
+  * the derived three-term roofline at TPU v5e peaks (see
+    benchmarks/roofline.py).
 
-Results accumulate in a JSON ledger (default: experiments/dryrun.json) that
-EXPERIMENTS.md's tables are generated from.
+``main()`` forces 512 placeholder CPU devices (``XLA_FLAGS``) before JAX
+initializes; importing this module changes nothing.  Results accumulate in
+a JSON ledger (default: experiments/dryrun.json).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch minitron-8b --shape train_4k
@@ -26,6 +21,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -40,7 +36,7 @@ from ..models import model as M
 from ..optim import adamw_init
 from ..training.loop import make_train_step
 from ..optim.schedules import linear_warmup_cosine
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh, mesh_chips
+from .mesh import V5E, device_peaks, make_production_mesh, mesh_chips
 from .sharding import (
     batch_pspecs,
     cache_pspecs,
@@ -137,8 +133,8 @@ def build_step(cfg, shape, mesh, *, autochunk_budget=None):
         b_sh = to_shardings(mesh, batch_pspecs(cfg, b_specs, mesh))
 
         def prefill_step(params, batch):
-            logits, aux = M.forward(cfg, params, batch, window=window)
-            return logits[:, -1, :]  # next-token logits (serving semantics)
+            # next-token logits (serving semantics)
+            return M.next_token_logits(cfg, params, batch, window=window)
 
         dp = ("pod", "data") if "pod" in mesh.axis_names else "data"
         out_sh = NamedSharding(
@@ -214,6 +210,7 @@ def dryrun_one(
     shape = INPUT_SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh_chips(mesh)
+    peaks = device_peaks(V5E)  # the pod the placeholder devices stand for
     t0 = time.time()
     try:
         fn, arg_specs, in_sh, out_sh = build_step(
@@ -250,9 +247,9 @@ def dryrun_one(
                 "generated_code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
             },
             roofline={
-                "compute_s": flops_dev / PEAK_FLOPS_BF16,
-                "memory_s": bytes_dev / HBM_BW,
-                "collective_s": coll_dev / ICI_BW,
+                "compute_s": flops_dev / peaks["bf16_flops"],
+                "memory_s": bytes_dev / peaks["hbm_bytes_per_s"],
+                "collective_s": coll_dev / peaks["ici_bytes_per_s"],
             },
         )
         terms = rec["roofline"]
@@ -296,6 +293,8 @@ def save_ledger(path: str, ledger: Dict[str, Any]):
 
 
 def main():
+    # must precede JAX's backend initialization, which locks the device count
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", type=str, default=None)
     ap.add_argument("--shape", type=str, default=None, choices=list(INPUT_SHAPES))

@@ -8,10 +8,30 @@ from __future__ import annotations
 
 import jax
 
-# Hardware constants used by the roofline analysis (TPU v5e-class, per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
-ICI_BW = 50e9                 # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.devices()[i].device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect over four
+# links, i.e. 50 GB/s per link).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: {
+        "bf16_flops": 197e12,      # FLOP/s
+        "hbm_bytes_per_s": 819e9,  # bytes/s
+        "hbm_bytes": 16e9,
+        "ici_bytes_per_s": 50e9,   # bytes/s per link
+    },
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; an unknown device is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}"
+            f" (known: {sorted(PEAKS)})"
+        ) from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -72,6 +72,7 @@ from ..models import model as M
 from ..obs import metrics as obs_metrics
 from ..obs.tracing import TRACER
 from ..serving import PagedServeEngine, Request, ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def write_obs_outputs(args, engine) -> None:
@@ -243,9 +244,12 @@ def serve_paged(cfg, params, rng, args):
     write_obs_outputs(args, engine)
     for r in done[:3]:
         print(f"  req {r.rid}: {r.generated[:8]}...")
+    return engine
 
 
 def main(argv=None):
+    """Serve per ``argv``; returns the engine, drained, for inspection."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--local", action="store_true")
@@ -455,6 +459,7 @@ def main(argv=None):
     write_obs_outputs(args, engine)
     for r in done[:3]:
         print(f"  req {r.rid}: {r.generated[:8]}...")
+    return engine
 
 
 if __name__ == "__main__":

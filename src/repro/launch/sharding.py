@@ -70,7 +70,7 @@ def _divisible(dim_size: int, axis_size: int) -> bool:
 def param_pspecs(cfg, params_tree, mesh, *, fsdp: bool = False):
     """PartitionSpec pytree matching ``params_tree`` (arrays or specs)."""
     model_n = mesh.shape["model"]
-    data_n = mesh.shape["data"]
+    data_n = mesh.shape.get("data", 1)
 
     # Attention projections are head-sharded; when the head count doesn't
     # divide the model axis, a raw column shard would cut across heads and

@@ -9,6 +9,7 @@ from .model import (
     init_cache,
     init_params,
     mtp_logits,
+    next_token_logits,
     param_count,
     param_specs,
     prefill,
@@ -17,6 +18,7 @@ from .model import (
 __all__ = [
     "layers", "mla", "moe", "rglru", "ssm",
     "active_param_count", "cache_specs", "decode_step", "embed_inputs",
-    "forward", "init_cache", "init_params", "mtp_logits", "param_count",
+    "forward", "init_cache", "init_params", "mtp_logits",
+    "next_token_logits", "param_count",
     "param_specs", "prefill",
 ]

@@ -69,41 +69,50 @@ def rg_block_params(cfg, key):
     }
 
 
-def _stack(trees):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+def _layers(cfg, block_fn, keys):
+    """Per-layer params: one stacked tree (built by ``vmap``, so a layer
+    stack is one traced layer and one buffer per leaf) under
+    ``scan_layers``, else a list of trees."""
+    if cfg.scan_layers:
+        return jax.vmap(block_fn)(keys)
+    return [block_fn(k) for k in keys]
 
 
+@partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
-    """Materialize parameters (use only on reduced configs on CPU)."""
+    """Materialize parameters on the default device.
+
+    Jitted, so a full-width model is built in place: the device holds one
+    copy of the weights at peak, never per-layer copies plus their stack.
+    """
     ks = jax.random.split(key, cfg.n_layers + 4)
     p: Dict[str, Any] = {"embed": L.embed_params(cfg, ks[0])}
     p["final_norm"] = L.norm_params(cfg, ks[1], cfg.d_model)
+    layer_keys = ks[2: 2 + cfg.n_layers]
 
     fam = cfg.family
-    if fam in ("dense", "vlm"):
-        blocks = [dense_block_params(cfg, ks[2 + i]) for i in range(cfg.n_layers)]
-        p["blocks"] = _stack(blocks) if cfg.scan_layers else blocks
-    elif fam in ("encoder", "audio"):
-        blocks = [dense_block_params(cfg, ks[2 + i]) for i in range(cfg.n_layers)]
-        p["blocks"] = _stack(blocks) if cfg.scan_layers else blocks
+    if fam in ("dense", "vlm", "encoder", "audio"):
+        p["blocks"] = _layers(
+            cfg, lambda k: dense_block_params(cfg, k), layer_keys
+        )
     elif fam == "moe":
+        nd = cfg.first_k_dense
         p["dense_blocks"] = [
-            dense_block_params(cfg, ks[2 + i], d_ff=cfg.d_ff)
-            for i in range(cfg.first_k_dense)
+            dense_block_params(cfg, layer_keys[i], d_ff=cfg.d_ff)
+            for i in range(nd)
         ]
-        moe_blocks = [
-            moe_block_params(cfg, ks[2 + cfg.first_k_dense + i])
-            for i in range(cfg.n_layers - cfg.first_k_dense)
-        ]
-        p["blocks"] = _stack(moe_blocks) if cfg.scan_layers else moe_blocks
+        p["blocks"] = _layers(
+            cfg, lambda k: moe_block_params(cfg, k), layer_keys[nd:]
+        )
     elif fam == "ssm":
-        blocks = [ssm_block_params(cfg, ks[2 + i]) for i in range(cfg.n_layers)]
-        p["blocks"] = _stack(blocks) if cfg.scan_layers else blocks
+        p["blocks"] = _layers(
+            cfg, lambda k: ssm_block_params(cfg, k), layer_keys
+        )
     elif fam == "hybrid":
         p["blocks"] = [
-            dense_block_params(cfg, ks[2 + i])
+            dense_block_params(cfg, layer_keys[i])
             if cfg.is_attention_layer(i)
-            else rg_block_params(cfg, ks[2 + i])
+            else rg_block_params(cfg, layer_keys[i])
             for i in range(cfg.n_layers)
         ]
     else:
@@ -240,7 +249,7 @@ _AC_CACHE: Dict[Any, Any] = {}
 def _maybe_autochunk(cfg, tag: str, fn, args):
     if not cfg.autochunk_budget:
         return fn
-    from ..core import ChunkConfig, ChunkedFunction
+    from ..core import ChunkConfig, autochunk
 
     # one ChunkedFunction per (config, budget, block): it compiles lazily per
     # input shape and replays one searched plan across every sequence length
@@ -256,12 +265,38 @@ def _maybe_autochunk(cfg, tag: str, fn, args):
             # chunking it would fight the mesh sharding (see core/search.py)
             dim_blocklist=(0,),
         )
-        _AC_CACHE[key] = ChunkedFunction(fn, chunk_cfg)
+        _AC_CACHE[key] = autochunk(fn, chunk_cfg)
     return _AC_CACHE[key]
+
+
+def autochunk_results(cfg: ModelConfig) -> list:
+    """Compile reports (``AutoChunkResult``) of the AutoChunk'd block
+    functions ``forward`` built for ``cfg`` so far, most recent per block."""
+    return [
+        cf.autochunk_result for key, cf in _AC_CACHE.items()
+        if key[3] == cfg and cf.autochunk_result is not None
+    ]
 
 
 def forward(cfg: ModelConfig, params, batch, *, window=None, remat: bool = False):
     """Full-sequence forward.  Returns (logits, aux_loss_scalar)."""
+    h, aux = forward_hidden(cfg, params, batch, window=window, remat=remat)
+    return L.unembed(cfg, params["embed"], h), aux
+
+
+def next_token_logits(cfg: ModelConfig, params, batch, *, window=None):
+    """Prefill semantics: logits (B, V) of the last position only.
+
+    Only that position is unembedded, so no (B, S, V) logits array exists —
+    at a 256k vocabulary it would outweigh the attention activations.
+    """
+    h, _ = forward_hidden(cfg, params, batch, window=window)
+    return L.unembed(cfg, params["embed"], h[:, -1])
+
+
+def forward_hidden(cfg: ModelConfig, params, batch, *, window=None,
+                   remat: bool = False):
+    """Full-sequence forward up to the final norm.  Returns (h, aux)."""
     h, positions = embed_inputs(cfg, params, batch)
     aux = jnp.zeros((), jnp.float32)
     fam = cfg.family
@@ -360,9 +395,7 @@ def forward(cfg: ModelConfig, params, batch, *, window=None, remat: bool = False
     else:
         raise ValueError(fam)
 
-    h = L.apply_norm(cfg, h, params["final_norm"])
-    logits = L.unembed(cfg, params["embed"], h)
-    return logits, aux
+    return L.apply_norm(cfg, h, params["final_norm"]), aux
 
 
 def mtp_logits(cfg, params, batch, h_final):

@@ -70,7 +70,7 @@ def _inner_peak(eqn, exclude: FrozenSet[int]) -> int:
         closed = params.get("jaxpr")
     elif name == "while":
         closed = params.get("body_jaxpr")
-    elif name in ("pjit", "jit", "closed_call", "remat", "checkpoint",
+    elif name in ("jit", "closed_call", "remat", "checkpoint",
                   "custom_jvp_call", "custom_vjp_call"):
         closed = params.get("jaxpr") or params.get("call_jaxpr")
     if closed is None:

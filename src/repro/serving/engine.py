@@ -36,6 +36,7 @@ in flight).
 """
 from __future__ import annotations
 
+import functools
 import time
 from contextlib import nullcontext
 from dataclasses import dataclass, field
@@ -77,12 +78,12 @@ def _normalize_mesh(mesh):
 
 def _shard_params(cfg, params, mesh):
     """Place params onto ``mesh``: tensor-parallel via the launch-layer
-    rules when the mesh has the ``data``/``model`` axes they name,
-    replicated otherwise (computation follows data under GSPMD)."""
+    rules when the mesh has the ``model`` axis they name, replicated
+    otherwise (computation follows data under GSPMD)."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     names = set(mesh.axis_names)
-    if "model" in names and "data" in names:
+    if "model" in names:
         from ..launch.sharding import param_pspecs, to_shardings
 
         shardings = to_shardings(mesh, param_pspecs(cfg, params, mesh))
@@ -108,6 +109,9 @@ class Request:
     # inserted (e.g. one-off prompts that would only pollute the radix tree)
     cache_prefix: bool = True
     generated: List[int] = field(default_factory=list)
+    # logits that chose generated[0] (a device array, read by correctness
+    # checks against a reference forward)
+    first_logits: Any = None
     done: bool = False
     # monotonic timestamps (time.perf_counter): ttft_s/latency_s are
     # durations, immune to wall-clock steps.  Not comparable across
@@ -387,16 +391,16 @@ class ServeEngine:
             dp_sh = NamedSharding(
                 self.mesh, PartitionSpec(_dp_axis(self.mesh_spec))
             )
+            self._cache_sharding = jax.tree.map(lambda _: dp_sh, self.cache)
             self._decode_wave = jax.jit(
                 decode_wave,
-                in_shardings=(
-                    jax.tree.map(lambda _: dp_sh, self.cache), dp_sh, dp_sh
-                ),
+                in_shardings=(self._cache_sharding, dp_sh, dp_sh),
             )
         else:
             self._decode_wave = jax.jit(decode_wave)
+        cfg, exec_len = self.cfg, self.exec_len
         self._prefill = jax.jit(
-            lambda batch: M.prefill(self.cfg, self.params, batch, self.exec_len)
+            lambda params, batch: M.prefill(cfg, params, batch, exec_len)
         )
         self.exec_stats["wave_compiles"] += 1
         self._wave_cache[wave_key] = (
@@ -477,13 +481,18 @@ class ServeEngine:
             toks = jnp.asarray([req.prompt], dtype=jnp.int32)
             with self._obs.span("serve.prefill_chunk", rid=req.rid,
                                 tokens=len(req.prompt)):
-                logits, cache1 = self._prefill({"tokens": toks})
+                logits, cache1 = self._prefill(self.params, {"tokens": toks})
             self.cache = jax.tree.map(
                 lambda full, r: full.at[slot].set(r), self.cache, cache1
             )
+            if self.mesh is not None:
+                # the row comes back laid out like the (sharded) weights;
+                # the wave takes the cache slot-sharded
+                self.cache = jax.device_put(self.cache, self._cache_sharding)
             # first token follows the engine's sampling mode, same as step():
             # greedy argmax, otherwise a categorical draw from the prefill
             # logits with the engine PRNG key
+            req.first_logits = logits[0, -1]
             if self.greedy:
                 first = int(jnp.argmax(logits[0, -1]))
             else:
@@ -742,6 +751,20 @@ class PagedServeEngine:
         self.pool = KVPool.for_config(
             cfg, num_pages=num_pages, page_size=page_size
         )
+        # tensor parallelism over KV heads: each device of the mesh's
+        # ``model`` axis holds its heads' K/V rows of every page, and runs
+        # the paged kernel on them (see _step_fn)
+        self._head_axis = None
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            m = dict(self.mesh.shape).get("model", 1)
+            if m > 1 and cfg.n_kv_heads % m == 0 and cfg.n_heads % m == 0:
+                self._head_axis = "model"
+            self.pool.pages = jax.device_put(
+                self.pool.pages,
+                NamedSharding(self.mesh, P(None, None, None, self._head_axis)),
+            )
         self.max_pages_per_seq = self.pool.pages_for(max_len)
         # prefix-sharing radix cache: admission matches cached prompt
         # prefixes onto ref-shared pool pages and skips their prefill;
@@ -793,7 +816,7 @@ class PagedServeEngine:
         if q_max in self._step_fns:
             return self._step_fns[q_max]
 
-        cfg, params = self.cfg, self.params
+        cfg = self.cfg
         from ..kernels import ops
         from ..kernels.paged_attention import (
             interleave_kv,
@@ -824,12 +847,25 @@ class PagedServeEngine:
                 pages_per_step = int(tuning.paged["pages_per_step"])
             self.kernel_tuning = tuning
 
-        def layer_params(i):
-            if cfg.scan_layers:
-                return jax.tree.map(lambda a: a[i], params["blocks"])
-            return params["blocks"][i]
+        paged_attn = functools.partial(
+            paged_attention_blocked, pages_per_step=pages_per_step,
+            interpret=ops.interpret_default(),
+        )
+        if self._head_axis is not None:
+            # a Pallas kernel cannot be partitioned by GSPMD: run it per
+            # device on that device's query heads and pool heads
+            from jax.sharding import PartitionSpec as P
 
-        def step(pages, tokens, q_lens, kv_lens, page_table):
+            heads = P(None, None, self._head_axis)
+            paged_attn = jax.shard_map(
+                paged_attn, mesh=self.mesh,
+                in_specs=(heads, heads, P(), P(), P()), out_specs=heads,
+                check_vma=False,
+            )
+
+        def step(params, pages, tokens, q_lens, kv_lens, page_table):
+            # the weights are an argument, never closed over: a closed-over
+            # array would be baked into the program as a constant.
             # tokens: (S, q_max) int32; q_lens/kv_lens: (S,) int32 with
             # kv_lens counting context INCLUDING this step's new tokens;
             # page_table: (S, mp) int32
@@ -843,26 +879,32 @@ class PagedServeEngine:
             slots = phys * ps + positions % ps
             slots = jnp.where(valid, slots, trash_slot).reshape(-1)
 
-            h = L.embed(cfg, params["embed"], tokens)  # (S, q_max, d)
-            for i in range(cfg.n_layers):
-                p = layer_params(i)
+            def layer(h, inp):
+                p, layer_pages = inp
                 hn = L.apply_norm(cfg, h, p["ln1"])
                 q, k, v = L.attn_project_qkv(cfg, p["attn"], hn, positions)
                 new_kv = interleave_kv(
                     k.reshape(S * q_max, cfg.n_kv_heads, cfg.hd),
                     v.reshape(S * q_max, cfg.n_kv_heads, cfg.hd),
-                ).astype(pages.dtype)
-                flat = pages[i].reshape(n_flat, 2 * cfg.n_kv_heads, cfg.hd)
-                flat = flat.at[slots].set(new_kv)
-                pages = pages.at[i].set(flat.reshape(pages.shape[1:]))
-                o = paged_attention_blocked(
-                    q, pages[i], page_table, q_lens, kv_lens,
-                    pages_per_step=pages_per_step,
-                    interpret=ops.INTERPRET,
-                )
+                ).astype(layer_pages.dtype)
+                flat = layer_pages.reshape(n_flat, 2 * cfg.n_kv_heads, cfg.hd)
+                layer_pages = flat.at[slots].set(new_kv).reshape(
+                    layer_pages.shape)
+                o = paged_attn(q, layer_pages, page_table, q_lens, kv_lens)
                 h = h + o.reshape(S, q_max, -1) @ p["attn"]["wo"]
                 hn = L.apply_norm(cfg, h, p["ln2"])
-                h = h + L.mlp(cfg, p["mlp"], hn)
+                return h + L.mlp(cfg, p["mlp"], hn), layer_pages
+
+            h = L.embed(cfg, params["embed"], tokens)  # (S, q_max, d)
+            if cfg.scan_layers:
+                # one traced layer: compile time does not grow with depth
+                h, pages = jax.lax.scan(layer, h, (params["blocks"], pages))
+            else:
+                new_pages = []
+                for i, p in enumerate(params["blocks"]):
+                    h, layer_pages = layer(h, (p, pages[i]))
+                    new_pages.append(layer_pages)
+                pages = jnp.stack(new_pages)
 
             h = L.apply_norm(cfg, h, params["final_norm"])
             last = h[jnp.arange(S), jnp.clip(q_lens - 1, 0, q_max - 1)]
@@ -1044,6 +1086,7 @@ class PagedServeEngine:
         with self._obs.span(batch_span, prefill_rows=n_prefill_rows,
                             decode_rows=n_decode_rows, q_max=q_max):
             logits, self.pool.pages = fn(
+                self.params,
                 self.pool.pages,
                 jnp.asarray(tokens),
                 jnp.asarray(q_lens),
@@ -1086,6 +1129,7 @@ class PagedServeEngine:
             for row, st, finished_prefill in need_rows:
                 st.req.generated.append(int(nxt[row]))
                 if finished_prefill:
+                    st.req.first_logits = logits[row]
                     stats.bump("prefill_chunks")
                     self.sched_stats["prefill_chunks"] += 1
                     st.req.first_token_at = now

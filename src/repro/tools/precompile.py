@@ -98,6 +98,9 @@ def precompile_one(
 
 
 def main(argv=None) -> int:
+    from ..launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.tools.precompile", description=__doc__
     )

@@ -233,6 +233,57 @@ def test_tuned_tiles_legal_on_non_divisible_shapes():
     np.testing.assert_allclose(np.asarray(out), want, atol=ATOL)
 
 
+def test_legal_block_never_returns_a_block_mosaic_refuses():
+    from repro.kernels.tiling import LANE, is_legal_block, legal_block
+
+    # no 8-aligned divisor of 60 fits 32: the whole axis, not 30
+    assert legal_block(60, 32) == 60
+    assert legal_block(4096, 1000) == 512
+    for total in (1, 7, 60, 96, 128, 9216):
+        for want in (1, 16, 64, 256, 512):
+            b = legal_block(total, want)
+            assert is_legal_block(total, b), (total, want, b)
+            b = legal_block(total, want, align=LANE)
+            assert is_legal_block(total, b, align=LANE), (total, want, b)
+
+
+def test_ffn_default_tiles_fit_vmem_at_real_width():
+    from repro.kernels.chunked_ffn import ffn_blocks
+    from repro.kernels.tiling import VMEM_BUDGET, ffn_vmem_bytes
+
+    bs, bf = ffn_blocks(1024, 3072, 9216, 2)
+    assert bf % 128 == 0 and 9216 % bf == 0
+    assert ffn_vmem_bytes(bs, bf, 3072, 2) <= VMEM_BUDGET
+
+
+def test_measured_autotune_counts_failures_and_raises_when_none_compiles(
+        monkeypatch, capsys):
+    site = {"kind": "swiglu", "s": 64, "d": 32, "f": 1024}
+
+    def refuse_wide(kind, s, cand):
+        if cand["block_f"] > 256:
+            raise RuntimeError("RESOURCE_EXHAUSTED: vmem")
+        return float(cand["block_s"])
+
+    at.clear_cache()
+    monkeypatch.setattr(at, "_measured_cost", refuse_wide)
+    before = stats.snapshot()
+    tuning = at.tune_sites([site], interpret=False)
+    failed = stats.delta(before)["autotune_failures"]
+    assert failed > 0
+    assert tuning.swiglu["block_f"] == 256
+    assert f"{failed} of" in capsys.readouterr().out
+
+    def refuse_all(kind, s, cand):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    at.clear_cache()
+    monkeypatch.setattr(at, "_measured_cost", refuse_all)
+    with pytest.raises(RuntimeError, match="no swiglu candidate compiled"):
+        at.tune_sites([site], interpret=False)
+    at.clear_cache()
+
+
 # ---------------------------------------------------------------------------
 # plan schema
 

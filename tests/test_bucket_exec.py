@@ -151,9 +151,7 @@ def test_bucket_exec_zero_traces_zero_compiles_on_warm_bucket():
     # one-executable-per-bucket invariant: the canonical jit holds exactly
     # one XLA executable no matter how many lengths it served
     exec_ = next(iter(cf._bucket_execs.values()))
-    size = exec_.xla_cache_size()
-    if size is not None:
-        assert size == 1
+    assert exec_.xla_cache_size() == 1
 
     # repeat length: memoized padded wrapper, still zero compile work
     before = stats.snapshot()

@@ -142,6 +142,19 @@ def test_property_any_candidate_is_output_preserving(seed, s, d):
     assert checked > 0 or not cands
 
 
+@pytest.mark.parametrize("budget", [0.875, 0.9])
+def test_loose_budget_never_increases_peak(budget):
+    # at these budgets the cheapest stage's rewrite-time estimate fits, but
+    # its emitted program estimates above the unchunked one: the unchunked
+    # program is kept
+    w = _mini_weights(key=0)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 32))
+    res = build_autochunk(_mini_block, (w, x), budget_ratio=budget)
+    assert res.final_peak <= res.baseline_peak
+    np.testing.assert_allclose(np.asarray(res.fn(w, x)),
+                               np.asarray(_mini_block(w, x)), atol=1e-5)
+
+
 @settings(max_examples=10, deadline=None)
 @given(budget=st.floats(0.05, 0.9), seed=st.integers(0, 100))
 def test_property_budget_never_increases_peak(budget, seed):

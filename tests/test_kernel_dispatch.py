@@ -149,6 +149,34 @@ def test_swiglu_dispatch_split_weights_odd_seq():
     np.testing.assert_allclose(np.asarray(on.fn(w, x)), y_ref, atol=ATOL)
 
 
+def test_dispatch_error_falls_back_and_is_counted(monkeypatch):
+    """A matcher that raises leaves scan codegen in place and bumps
+    ``kernel_dispatch_errors``, not ``kernel_dispatch_misses``."""
+    from repro.core import kernel_dispatch as KD
+
+    def boom(ctx, mask_mode="auto"):
+        raise TypeError("matcher bug")
+
+    monkeypatch.setattr(KD, "match_body", boom)
+    d, f = 32, 256
+    key = jax.random.PRNGKey(4)
+    w = {
+        "w_in": jax.random.normal(key, (d, 2 * f)) * 0.1,
+        "w_out": jax.random.normal(jax.random.fold_in(key, 1), (f, d)) * 0.1,
+    }
+    x = jax.random.normal(jax.random.fold_in(key, 2), (2, 48, d))
+    before = stats.snapshot()
+    on = _compile(_swiglu_fused, (w, x), kernel_dispatch="on",
+                  weight_argnums=(0,))
+    delta = stats.delta(before)
+    assert delta["kernel_dispatch_errors"] >= 1
+    assert delta["kernel_dispatch_hits"] == 0
+    assert delta["kernel_dispatch_misses"] == 0
+    np.testing.assert_allclose(
+        np.asarray(on.fn(w, x)), np.asarray(_swiglu_fused(w, x)), atol=ATOL
+    )
+
+
 def test_geglu_does_not_dispatch():
     """gelu-gated FFN is NOT SwiGLU: matcher must refuse, output exact."""
     d, f = 32, 128

@@ -394,3 +394,35 @@ class TestMeshExecution:
         acc = eng_m.plan_accuracy()
         assert acc is not None and np.isfinite(acc.error_pct)
         assert acc.error_pct < 50.0
+
+    def test_paged_engine_head_sharded_token_exact(self):
+        """Under ``model=4`` the paged engine shards the KV pool over KV
+        heads and runs the paged kernel per device: same tokens as one
+        device."""
+        from repro.configs import get_config
+        from repro.models import model as M
+        from repro.serving import PagedServeEngine, Request
+
+        cfg = get_config("minitron-4b").reduced().with_(
+            dtype="float32", n_heads=8, n_kv_heads=4, sliding_window=None)
+        params = M.init_params(cfg, jax.random.PRNGKey(0))
+
+        def run(mesh):
+            eng = PagedServeEngine(cfg, params, max_seqs=3, max_len=64,
+                                   page_size=8, prefill_chunk=8, mesh=mesh)
+            rng = np.random.default_rng(0)
+            for i, n in enumerate([3, 20, 33]):
+                eng.submit(Request(
+                    rid=i,
+                    prompt=rng.integers(0, cfg.vocab_size, n).tolist(),
+                    max_new_tokens=4,
+                ))
+            done = eng.run()
+            return eng, {r.rid: r.generated for r in done}
+
+        eng_m, toks_m = run("model=4")
+        _, toks_p = run(None)
+        assert toks_m == toks_p
+        pages = eng_m.pool.pages
+        assert pages.sharding.spec[3] == "model"
+        assert pages.sharding.shard_shape(pages.shape)[3] == pages.shape[3] // 4

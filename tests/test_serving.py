@@ -369,3 +369,28 @@ def test_paged_engine_planned_prefill_chunk(setup):
     # unsatisfiable budget falls back to the min chunk with fits=False
     assert loose.prefill_plan.fits
     assert loose.prefill_plan.peak_bytes <= loose.prefill_plan.budget_bytes
+
+
+def test_paged_step_takes_weights_as_arguments(setup):
+    """The jitted paged step reads the weights as arguments: none of them is
+    baked into the program as a constant."""
+    import math
+    import re
+
+    from repro.serving import PagedServeEngine
+
+    cfg, params = setup
+    eng = PagedServeEngine(cfg, params, max_seqs=2, max_len=64, page_size=8)
+    S, q_max = eng.max_seqs, eng.prefill_chunk
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    text = eng._step_fn(q_max).lower(
+        params, eng.pool.pages, i32(S, q_max), i32(S), i32(S),
+        i32(S, eng.max_pages_per_seq),
+    ).as_text()
+    smallest_weight = min(x.size for x in jax.tree.leaves(params))
+    const_sizes = [
+        math.prod(int(d) for d in dims.split("x")[:-1])
+        for dims in re.findall(r"stablehlo\.constant dense<.*?> : tensor<([^>]*)>", text)
+    ]
+    assert const_sizes  # the parse found the program's constants
+    assert max(const_sizes) < smallest_weight

@@ -1,0 +1,9 @@
+"""Median host wall time of the window's engine steps that carry both
+prefill and decode rows (they end in ``device_get``), in ms."""
+import statistics
+
+
+def read(data):
+    ms = [1e3 * (s["t1"] - s["t0"]) for s in data.get("steps") or []
+          if s["kind"] == "mixed_steps"]
+    return statistics.median(ms) if ms else None

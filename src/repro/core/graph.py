@@ -67,12 +67,15 @@ def _inner_closed_jaxpr(eqn) -> Optional[jex_core.ClosedJaxpr]:
     return None
 
 
-def _flatten_jaxpr(jaxpr, consts, const_env: Dict[Var, Any], arg_atoms):
+def _flatten_jaxpr(jaxpr, consts, const_env: Dict[Var, Any], arg_atoms,
+                   name_stack=None):
     """Inline all call-like eqns, rewriting every defined var to a FRESH Var.
 
     jit caches inner jaxprs, so the SAME jaxpr object (and its Var objects)
     can appear at several call sites; per-call-site renaming keeps the flat
-    graph SSA.  Returns (eqns, resolved_out_atoms).
+    graph SSA.  An inlined eqn's name stack is prefixed with its call
+    site's (``name_stack``), so it keeps the caller's scopes.  Returns
+    (eqns, resolved_out_atoms).
     """
     sub: Dict[Var, Any] = {}
     for cv, cval in zip(jaxpr.constvars, consts):
@@ -85,6 +88,12 @@ def _flatten_jaxpr(jaxpr, consts, const_env: Dict[Var, Any], arg_atoms):
             return sub[a]
         return a  # literal, constvar, or top-level var
 
+    def scoped(source_info):
+        if name_stack is None:
+            return source_info
+        return source_info.replace(
+            name_stack=name_stack + source_info.name_stack)
+
     out: List[JaxprEqn] = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name in _CALL_PRIM_NAMES:
@@ -92,7 +101,8 @@ def _flatten_jaxpr(jaxpr, consts, const_env: Dict[Var, Any], arg_atoms):
             if inner is not None:
                 args = [resolve(a) for a in eqn.invars]
                 sub_eqns, inner_outs = _flatten_jaxpr(
-                    inner.jaxpr, inner.consts, const_env, args
+                    inner.jaxpr, inner.consts, const_env, args,
+                    scoped(eqn.source_info).name_stack,
                 )
                 out.extend(sub_eqns)
                 for ov, res in zip(eqn.outvars, inner_outs):
@@ -104,7 +114,8 @@ def _flatten_jaxpr(jaxpr, consts, const_env: Dict[Var, Any], arg_atoms):
             nv = fresh_var(v.aval)
             sub[v] = nv
             new_outvars.append(nv)
-        out.append(eqn.replace(invars=new_invars, outvars=new_outvars))
+        out.append(eqn.replace(invars=new_invars, outvars=new_outvars,
+                               source_info=scoped(eqn.source_info)))
     return out, [resolve(a) for a in jaxpr.outvars]
 
 

@@ -231,7 +231,9 @@ _NP_BINOPS = {
 }
 
 
-def _concrete_mask_value(ctx: _BodyCtx, v, depth: int = 0) -> Optional[np.ndarray]:
+def _concrete_mask_value(
+    ctx: _BodyCtx, v, depth: int = 0, why: Optional[List[str]] = None
+) -> Optional[np.ndarray]:
     """Concrete FULL-shape value behind a mask var, or None.
 
     Position masks are built from ``jnp.arange`` comparisons: the arange is
@@ -241,7 +243,9 @@ def _concrete_mask_value(ctx: _BodyCtx, v, depth: int = 0) -> Optional[np.ndarra
     chunk-adjusted, so the result is the full (Sq, Skv) mask even when the
     body's eqn params were shrunk to chunk size.  Anything outside the
     whitelisted position algebra (gathers, data-dependent masks...) returns
-    None and keeps the boolean-mask kernel.
+    None and keeps the boolean-mask kernel.  An intermediate larger than
+    ``_MASK_EVAL_LIMIT`` elements also returns None, and appends
+    ``"mask_eval_limit"`` to ``why`` when a list is given.
     """
     if not is_var(v):
         val = getattr(v, "val", None)
@@ -252,6 +256,8 @@ def _concrete_mask_value(ctx: _BodyCtx, v, depth: int = 0) -> Optional[np.ndarra
         return None
     shape = tuple(v.aval.shape)
     if _prod(shape) > _MASK_EVAL_LIMIT:
+        if why is not None:
+            why.append("mask_eval_limit")
         return None
     i = ctx.producer.get(v)
     if i is not None:
@@ -270,7 +276,7 @@ def _concrete_mask_value(ctx: _BodyCtx, v, depth: int = 0) -> Optional[np.ndarra
             [shape[dim] if d == dim else 1 for d in range(len(shape))]
         )
         return np.broadcast_to(base, shape)
-    ins = [_concrete_mask_value(ctx, iv, depth + 1) for iv in e.invars]
+    ins = [_concrete_mask_value(ctx, iv, depth + 1, why) for iv in e.invars]
     if any(x is None for x in ins):
         return None
     if nm == "broadcast_in_dim":
@@ -661,19 +667,32 @@ def _try_attention(
     # chunked along its q axis (each chunk sees rows [i*c, i*c + c) of the
     # full band — the builder rebuilds the global row offset from the loop
     # index), and K must not be chunked along kv (column positions must
-    # stay global).
+    # stay global).  ``bool_reason`` names the requirement a bool-mask
+    # dispatch failed (counted per dispatch, ``kernel_dispatch_bool_mask.*``).
     band = None
-    if mask_mode != "bool" and mask_shape == "2d":
-        q_axis = m_map.index(q_out)
-        m_chunk = ctx.var_dim.get(m_var)
-        if m_chunk == q_axis and ctx.var_dim.get(k_var) != k_seq:
-            mval = _concrete_mask_value(ctx, m_var)
-            if mval is not None and mval.ndim == 2 and mval.dtype == np.bool_:
+    bool_reason = None
+    if mask_mode == "bool":
+        bool_reason = "mask_mode_bool"
+    elif mask_shape != "2d":
+        bool_reason = "mask_not_2d"
+    elif ctx.var_dim.get(m_var) != m_map.index(q_out):
+        bool_reason = "mask_not_chunked_on_q"
+    elif ctx.var_dim.get(k_var) == k_seq:
+        bool_reason = "kv_chunked"
+    else:
+        why: List[str] = []
+        mval = _concrete_mask_value(ctx, m_var, why=why)
+        if mval is None:
+            bool_reason = why[0] if why else "mask_not_position_algebra"
+        else:
+            if mval.ndim == 2 and mval.dtype == np.bool_:
                 m2 = mval.T if mask_flip else mval
                 if mask_invert:
                     m2 = ~m2
                 if m2.shape == (sq_full, skv_full):
                     band = _band_params(m2)
+            if band is None:
+                bool_reason = "mask_not_band"
 
     root_dtype = root.aval.dtype
     scale_f = float(scale)
@@ -765,6 +784,7 @@ def _try_attention(
     )
     meta = {
         "mask": mask_variant,
+        "bool_reason": bool_reason,
         "site": {
             "kind": "attention", "n": n_site, "sq": sq_full,
             "skv": skv_full, "hd": hd_sz,
@@ -1121,9 +1141,15 @@ def dispatch_node(
         return 0
     refresh_node(node)
     stats.bump("kernel_dispatch_hits", len(records))
-    n_computed = sum(1 for m in matches if m.meta.get("mask") == "computed")
+    attention = [m for m in matches if m.kind == "attention"]
+    if attention:
+        stats.bump("kernel_dispatch_attention", len(attention))
+    n_computed = sum(1 for m in attention if m.meta["mask"] == "computed")
     if n_computed:
         stats.bump("kernel_dispatch_computed_mask", n_computed)
+    for m in attention:
+        if m.meta["bool_reason"] is not None:
+            stats.bump(f"kernel_dispatch_bool_mask.{m.meta['bool_reason']}")
     return len(records)
 
 

@@ -35,9 +35,9 @@ from typing import Any, Callable, Dict, FrozenSet, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.extend import source_info_util
 
 from . import stats
-from ..obs.tracing import TRACER, span
 from .graph import Graph, Var, atom_bytes, is_var
 from .search import ChunkCandidate
 
@@ -151,22 +151,39 @@ def _write_chunk(buf, val, dim: int, i, c: int):
     return lax.dynamic_update_slice_in_dim(buf, val, i * c, axis=dim)
 
 
+def _scope_of(eqn):
+    """The name stack ``eqn`` was traced under, below the current one."""
+    return source_info_util.current_name_stack() + eqn.source_info.name_stack
+
+
 def eval_eqns(eqns, env: Dict[Var, Any]) -> None:
-    """Interpret equations (including chunk_loop nodes) against ``env``."""
+    """Interpret equations (including chunk_loop nodes) against ``env``.
+
+    Each equation is bound under the source line and name stack it was
+    traced with, as ``jax.core.eval_jaxpr`` does, so the emitted program's
+    ops keep the model's scopes and source lines in their metadata.
+    """
     for eqn in eqns:
         if isinstance(eqn, ChunkLoopEqn):
             _eval_chunk_loop(eqn, env)
             continue
         invals = [env[iv] if is_var(iv) else iv.val for iv in eqn.invars]
         subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
-        ans = eqn.primitive.bind(*subfuns, *invals, **bind_params)
+        with source_info_util.user_context(eqn.source_info.traceback,
+                                           name_stack=_scope_of(eqn)):
+            ans = eqn.primitive.bind(*subfuns, *invals, **bind_params)
         outs = ans if eqn.primitive.multiple_results else [ans]
         for ov, o in zip(eqn.outvars, outs):
             env[ov] = o
 
 
 def _eval_body(body, benv: Dict[Var, Any], dispatches: Sequence[KernelDispatch]):
-    """Evaluate a loop body, substituting fused kernels where dispatched."""
+    """Evaluate a loop body, substituting fused kernels where dispatched.
+
+    A kernel runs under the name stack of the equation it replaces but not
+    under its source line: its ops keep the kernel's own ``pallas_call``
+    line, by which the benchmark names kernels.
+    """
     if not dispatches:
         eval_eqns(body, benv)
         return
@@ -175,7 +192,8 @@ def _eval_body(body, benv: Dict[Var, Any], dispatches: Sequence[KernelDispatch])
     for i, eqn in enumerate(body):
         d = fire.get(i)
         if d is not None:
-            benv[d.root] = d.fn(benv)
+            with source_info_util.set_name_stack(_scope_of(eqn)):
+                benv[d.root] = d.fn(benv)
             continue
         if i in skip:
             continue
@@ -193,21 +211,29 @@ def _eval_chunk_loop(node: ChunkLoopEqn, env: Dict[Var, Any]) -> None:
     # sliced in-body (no stacked copies).  dynamic_slice/update clamp the
     # final start index, so a non-divisible chunk count re-covers the tail
     # exactly (chunk outputs are pure functions of their input slices).
-    bufs0 = tuple(jnp.zeros(v.aval.shape, v.aval.dtype) for v in node.outvars)
+    # The loop and its chunk I/O carry scopes of their own, by which a
+    # device trace tells the cost of chunking from the model's work.
+    with jax.named_scope("autochunk"):
+        with jax.named_scope("autochunk.io"):
+            bufs0 = tuple(
+                jnp.zeros(v.aval.shape, v.aval.dtype) for v in node.outvars
+            )
 
-    def scan_body(bufs, i):
-        benv: Dict[Var, Any] = dict(captured)
-        benv[LOOP_INDEX] = i
-        for (v, d), full in zip(sliced, sliced_full):
-            benv[v] = _slice_chunk(full, d, i, c)
-        _eval_body(p["body"], benv, p["dispatches"])
-        bufs = tuple(
-            _write_chunk(buf, benv[v], d, i, c)
-            for buf, v, d in zip(bufs, node.outvars, out_dims)
-        )
-        return bufs, None
+        def scan_body(bufs, i):
+            benv: Dict[Var, Any] = dict(captured)
+            benv[LOOP_INDEX] = i
+            with jax.named_scope("autochunk.io"):
+                for (v, d), full in zip(sliced, sliced_full):
+                    benv[v] = _slice_chunk(full, d, i, c)
+            _eval_body(p["body"], benv, p["dispatches"])
+            with jax.named_scope("autochunk.io"):
+                bufs = tuple(
+                    _write_chunk(buf, benv[v], d, i, c)
+                    for buf, v, d in zip(bufs, node.outvars, out_dims)
+                )
+            return bufs, None
 
-    bufs, _ = lax.scan(scan_body, bufs0, jnp.arange(n_iters))
+        bufs, _ = lax.scan(scan_body, bufs0, jnp.arange(n_iters))
     for v, y in zip(node.outvars, bufs):
         env[v] = y
 
@@ -437,11 +463,9 @@ def apply_chunk(
     no tracing; applying a K-stage plan is K calls on one graph.
     """
     stats.bump("lowering_rewrites")
-    with span("lower.apply_chunk", region=(cand.s, cand.e),
-              n_chunks=n_chunks):
-        node = make_chunk_loop(g, cand, n_chunks)
-        if validate:
-            validate_body(node)
+    node = make_chunk_loop(g, cand, n_chunks)
+    if validate:
+        validate_body(node)
     nodes = (
         list(g.eqns[: cand.s])
         + [g.eqns[i] for i in cand.hoisted]
@@ -469,9 +493,6 @@ def emit(g: Graph) -> Callable[..., Tuple[Any, ...]]:
     of how many chunk stages the graph carries.
     """
     stats.bump("lowering_emits")
-    # emission itself is lazy (cost is paid at the verification re-trace);
-    # an instant event marks the single emit per compiled plan
-    TRACER.instant("lower.emit", eqns=len(g.eqns))
     consts = dict(g.consts)
     invars = list(g.invars)
     outvars = list(g.outvars)

@@ -52,8 +52,18 @@ _PIPELINE_COUNTERS = (
     "kernel_dispatch_errors",
     # attention dispatches whose mask classified as causal/sliding-window and
     # lowered onto the position-computed kernel (no (Sq,Skv) bool array ever
-    # exists); the remainder of kernel_dispatch_hits stream a boolean mask
+    # exists); the remainder of kernel_dispatch_attention stream a boolean
+    # mask, each counted under the one reason ``kernel_dispatch.
+    # _try_attention`` gives (``kernel_dispatch_bool_mask.<reason>``)
     "kernel_dispatch_computed_mask",
+    "kernel_dispatch_attention",
+    "kernel_dispatch_bool_mask.mask_mode_bool",
+    "kernel_dispatch_bool_mask.mask_not_2d",
+    "kernel_dispatch_bool_mask.mask_not_chunked_on_q",
+    "kernel_dispatch_bool_mask.kv_chunked",
+    "kernel_dispatch_bool_mask.mask_eval_limit",
+    "kernel_dispatch_bool_mask.mask_not_position_algebra",
+    "kernel_dispatch_bool_mask.mask_not_band",
     # kernel autotune (kernels.autotune): ``autotune_passes`` counts actual
     # candidate-grid evaluations (one per distinct site set per process —
     # warm plan replays and bucket hits restore the persisted KernelTuning

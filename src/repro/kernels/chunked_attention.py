@@ -153,7 +153,7 @@ def computed_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((N, Sq, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="computed_attention",
     )(off, q, k, v)
 
 
@@ -242,6 +242,7 @@ def masked_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="masked_attention",
     )(q, k, v, mask)
 
 

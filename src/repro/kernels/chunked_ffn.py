@@ -77,4 +77,5 @@ def chunked_ffn(
         out_shape=jax.ShapeDtypeStruct((S, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bs, d), jnp.float32)],
         interpret=interpret,
+        name="chunked_ffn",
     )(x, w_gate, w_up, w_down)

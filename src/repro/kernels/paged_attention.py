@@ -230,6 +230,7 @@ def paged_attention_blocked(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, q_lens, kv_lens, qg, *([kv_pages] * pages_per_step))
     out = out.reshape(S, nqb, Kv, bq, G, hd).transpose(0, 1, 3, 2, 4, 5)
     return out.reshape(S, q_max, H, hd)

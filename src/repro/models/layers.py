@@ -33,9 +33,10 @@ def layernorm(x, w, b, eps: float = 1e-5):
 
 
 def apply_norm(cfg, x, p):
-    if cfg.norm == "layernorm":
-        return layernorm(x, p["w"], p["b"])
-    return rmsnorm(x, p["w"])
+    with jax.named_scope("norm"):
+        if cfg.norm == "layernorm":
+            return layernorm(x, p["w"], p["b"])
+        return rmsnorm(x, p["w"])
 
 
 def norm_params(cfg, key, d):
@@ -97,16 +98,18 @@ def gqa_attention(
     Kv = k.shape[2]
     G = H // Kv
     scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, Sq, Kv, G, hd)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    mask = attention_scores_mask(q_pos, kv_pos, causal=causal, window=window)
-    if kv_valid is not None:
-        mask = mask & kv_valid[None, :]
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    a = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", a, v.astype(jnp.float32))
-    return out.reshape(B, Sq, H, hd).astype(q.dtype)
+    with jax.named_scope("attention"):
+        qg = q.reshape(B, Sq, Kv, G, hd)
+        logits = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
+                            k.astype(jnp.float32)) * scale
+        mask = attention_scores_mask(q_pos, kv_pos, causal=causal,
+                                     window=window)
+        if kv_valid is not None:
+            mask = mask & kv_valid[None, :]
+        logits = jnp.where(mask[None, None, None], logits, -1e30)
+        a = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bkgqs,bskd->bqkgd", a, v.astype(jnp.float32))
+        return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
 def attn_params(cfg, key, *, d=None, n_heads=None, n_kv=None, hd=None):
@@ -129,12 +132,13 @@ def attn_project_qkv(cfg, p, x, positions, *, n_heads=None, n_kv=None, hd=None):
     n_kv = n_kv or cfg.n_kv_heads
     hd = hd or cfg.hd
     B, S, _ = x.shape
-    q = (x @ p["wq"]).reshape(B, S, n_heads, hd)
-    k = (x @ p["wk"]).reshape(B, S, n_kv, hd)
-    v = (x @ p["wv"]).reshape(B, S, n_kv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    return q, k, v
+    with jax.named_scope("qkv"):
+        q = (x @ p["wq"]).reshape(B, S, n_heads, hd)
+        k = (x @ p["wk"]).reshape(B, S, n_kv, hd)
+        v = (x @ p["wv"]).reshape(B, S, n_kv, hd)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        return q, k, v
 
 
 # --------------------------------------------------------------------------
@@ -155,16 +159,17 @@ def mlp_params(cfg, key, *, d=None, f=None, act=None):
 
 def mlp(cfg, p, x, act=None):
     act = act or cfg.act
-    h = x @ p["w_in"]
-    if act == "swiglu":
-        u, g = jnp.split(h, 2, axis=-1)
-        h = u * jax.nn.silu(g)
-    elif act == "geglu":
-        u, g = jnp.split(h, 2, axis=-1)
-        h = u * jax.nn.gelu(g)
-    else:
-        h = jax.nn.gelu(h)
-    return h @ p["w_out"]
+    with jax.named_scope("mlp"):
+        h = x @ p["w_in"]
+        if act == "swiglu":
+            u, g = jnp.split(h, 2, axis=-1)
+            h = u * jax.nn.silu(g)
+        elif act == "geglu":
+            u, g = jnp.split(h, 2, axis=-1)
+            h = u * jax.nn.gelu(g)
+        else:
+            h = jax.nn.gelu(h)
+        return h @ p["w_out"]
 
 
 # --------------------------------------------------------------------------
@@ -187,8 +192,9 @@ def embed(cfg, p, tokens):
 
 
 def unembed(cfg, p, h):
-    logits = h @ (p["embedding"].T if cfg.tie_embeddings else p["lm_head"])
-    if cfg.vocab_padded != cfg.vocab_size:
-        pad = jnp.arange(cfg.vocab_padded) >= cfg.vocab_size
-        logits = jnp.where(pad, -1e30, logits.astype(jnp.float32)).astype(logits.dtype)
-    return logits
+    with jax.named_scope("unembed"):
+        logits = h @ (p["embedding"].T if cfg.tie_embeddings else p["lm_head"])
+        if cfg.vocab_padded != cfg.vocab_size:
+            pad = jnp.arange(cfg.vocab_padded) >= cfg.vocab_size
+            logits = jnp.where(pad, -1e30, logits.astype(jnp.float32)).astype(logits.dtype)
+        return logits

@@ -166,15 +166,24 @@ def attn_apply_full(cfg, p, x, positions=None, *, window, causal):
         o = L.gqa_attention(
             q, k, v, q_pos=positions, kv_pos=positions, causal=causal, window=window
         )
-        o = o.reshape(x.shape[0], x.shape[1], -1) @ p["attn"]["wo"]
-    return x + o
+    with jax.named_scope("attn_out"):
+        if not cfg.mla:
+            o = o.reshape(x.shape[0], x.shape[1], -1) @ p["attn"]["wo"]
+        return x + o
+
+
+def _mlp_residual(cfg, p, x, h):
+    """``x`` plus the FFN of ``h``, the residual add in the FFN's scope."""
+    out = L.mlp(cfg, p["mlp"], h)
+    with jax.named_scope("mlp"):
+        return x + out
 
 
 def dense_block_full(cfg, p, x, positions=None, *, window=None, causal=None):
     causal = cfg.causal if causal is None else causal
     x = attn_apply_full(cfg, p, x, positions, window=window, causal=causal)
     h = L.apply_norm(cfg, x, p["ln2"])
-    return x + L.mlp(cfg, p["mlp"], h)
+    return _mlp_residual(cfg, p, x, h)
 
 
 def moe_block_full(cfg, p, x, positions=None, *, window=None):
@@ -195,7 +204,7 @@ def rg_block_full(cfg, p, x):
     y, _ = RG.recurrent_block(cfg, p["rec"], h)
     x = x + y
     h = L.apply_norm(cfg, x, p["ln2"])
-    return x + L.mlp(cfg, p["mlp"], h)
+    return _mlp_residual(cfg, p, x, h)
 
 
 # ===========================================================================
@@ -530,8 +539,8 @@ def attn_block_decode(cfg, p, x, cache, pos, *, window=None, local=False):
             q_pos=jnp.full((1,), pos, jnp.int32), kv_pos=kv_pos,
             causal=True, window=win, kv_valid=valid,
         )
-        o = o.reshape(B, 1, -1) @ p["attn"]["wo"]
-        x = x + o
+        with jax.named_scope("attn_out"):
+            x = x + o.reshape(B, 1, -1) @ p["attn"]["wo"]
         new_cache = {"k": ck, "v": cv}
     return x, new_cache
 

@@ -2,8 +2,9 @@
 
 Three legs (see each submodule):
 
-* :mod:`repro.obs.tracing` — nested spans over the compile pipeline and
-  the serving step loop, exported as Chrome-trace/Perfetto JSON.
+* :mod:`repro.obs.tracing` — nested spans over the compile pipeline, the
+  serving step loop and jax's own jit compile stages, exported as
+  Chrome-trace/Perfetto JSON and shown on a ``jax.profiler`` trace.
 * :mod:`repro.obs.metrics` — typed metrics registry (counters / gauges /
   fixed-bucket histograms) with Prometheus text exposition and JSON
   snapshots.  ``core.stats`` is now a thin compat shim over this.

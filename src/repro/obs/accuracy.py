@@ -153,8 +153,13 @@ def device_bytes_in_use(device=None) -> Optional[int]:
 
 
 def device_peak_bytes(device=None) -> Optional[int]:
+    """Peak device bytes: the peak in use plus the peak reserved.  On a
+    TPU an executable's temporaries are reserved apart from the buffers in
+    use, so the peak in use alone leaves them out."""
     st = device_memory_stats(device)
-    return None if st is None else st.get("peak_bytes_in_use")
+    if st is None or "peak_bytes_in_use" not in st:
+        return None
+    return int(st["peak_bytes_in_use"]) + int(st.get("peak_bytes_reserved", 0))
 
 
 # ---------------------------------------------------------------------------
@@ -227,8 +232,9 @@ def per_device_accuracy(
     ``repro.core``; this module stays importable without it), so the
     partitioned measurement is ``watermark / peak_divisor`` — the same
     structural watermark, charged at the device's shard of every var.
-    Where the backend exposes allocator stats, the current per-device
-    ``peak_bytes_in_use`` rides along in ``extra`` for the serving layer.
+    Where the backend exposes allocator stats, the device's current peak
+    (:func:`device_peak_bytes`) rides along in ``extra`` for the serving
+    layer.
     """
     full = watermark_jaxpr(closed_jaxpr, exclude_nbytes=exclude_nbytes)
     div = float(peak_divisor) if peak_divisor and peak_divisor > 0 else 1.0
@@ -242,7 +248,7 @@ def per_device_accuracy(
     )
     dev_peak = device_peak_bytes(device)
     if dev_peak is not None:
-        acc.extra["device_peak_bytes_in_use"] = int(dev_peak)
+        acc.extra["device_peak_bytes"] = int(dev_peak)
     return acc
 
 

@@ -27,6 +27,7 @@ imports us; a cycle would break the package).
 from __future__ import annotations
 
 import json
+import re
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -174,6 +175,12 @@ def _fmt(v: float) -> str:
     return repr(float(v))
 
 
+def _prom_name(name: str) -> str:
+    """``name`` in the characters the text format allows: a counter such
+    as ``kernel_dispatch_bool_mask.kv_chunked`` is exposed with ``_``."""
+    return re.sub(r"[^a-zA-Z0-9_:]", "_", name)
+
+
 class MetricsRegistry:
     """Process-wide named metric store.  ``counter/gauge/histogram`` are
     get-or-create: repeat registration with the same name returns the
@@ -256,7 +263,8 @@ class MetricsRegistry:
         there is a golden test against this exact rendering)."""
         with self._lock:
             lines: List[str] = []
-            for n, m in sorted(self._metrics.items()):
+            for key, m in sorted(self._metrics.items()):
+                n = _prom_name(key)
                 if m.help:
                     lines.append(f"# HELP {n} {m.help}")
                 if isinstance(m, Counter):

@@ -15,7 +15,20 @@ Spans nest through a per-thread stack; each completed span records
 the process-wide :data:`TRACER`.  Recording is append-only under a lock —
 no I/O, no device syncs — and a disabled tracer short-circuits to a
 no-op, so instrumented hot paths pay one attribute read when tracing is
-off.
+off.  An enabled span is also a ``jax.profiler.TraceAnnotation`` of the
+same name, so a profiler trace taken around it shows it on the host's
+timeline beside the device's ops (one call into the profiler when none is
+running).
+
+A ``jax.monitoring`` listener, registered at import, records jax's own
+compile stages of every jit on :data:`TRACER` (``jit.trace``,
+``jit.lower``, and ``jit.compile`` or, when the persistent compilation
+cache held the executable, ``jit.cache_load``; each with the ``fun`` jax
+names) and counts the persistent cache's hits and misses
+(``jit_cache_hits`` / ``jit_cache_misses`` in the metrics registry).
+jax reports a stage once it is done, so these spans are recorded after
+the fact, with start = end - duration, and never enclose a span opened
+meanwhile.
 
 :meth:`Tracer.to_chrome` renders the buffer as Chrome-trace JSON
 (``"X"`` complete events, microsecond timestamps), which Perfetto and
@@ -35,6 +48,10 @@ from contextlib import contextmanager
 from functools import wraps
 from typing import Any, Dict, List, Optional
 
+from jax import monitoring
+from jax.profiler import TraceAnnotation
+
+from . import metrics
 from .clock import Clock, perf_clock
 
 
@@ -112,7 +129,8 @@ class Tracer:
                               args or None)
         stack.append(open_span)
         try:
-            yield open_span
+            with TraceAnnotation(name):
+                yield open_span
         finally:
             stack.pop()
             self._record(open_span, self._clock())
@@ -125,12 +143,18 @@ class Tracer:
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration marker event."""
+        self.record_done(name, 0.0, **args)
+
+    def record_done(self, name: str, duration: float, **args) -> None:
+        """A span of ``duration`` seconds that ended now: work reported
+        once it is done.  It is never on the stack, so it is nobody's
+        parent."""
         if not self.enabled:
             return
         now = self._clock()
         stack = self._stack()
         parent = stack[-1].name if stack else None
-        self._record(_OpenSpan(name, now, len(stack), parent,
+        self._record(_OpenSpan(name, now - duration, len(stack), parent,
                                args or None), now)
 
     # -- inspection / export ----------------------------------------------
@@ -175,6 +199,64 @@ class Tracer:
 
 
 TRACER = Tracer()
+
+
+class JitListener:
+    """Records jax's compile stages on a tracer (see the module doc).
+
+    jax reports, in this order and on the compiling thread: the jaxpr
+    trace, the lowering to MLIR, then for a persistent-cache hit the
+    cache's hit and its retrieval time, and last the backend compile,
+    whose duration holds the retrieval.  A backend compile that follows a
+    retrieval is therefore recorded as ``jit.cache_load``.
+    """
+
+    SPANS = {
+        "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+        "/jax/core/compile/backend_compile_duration": "jit.compile",
+    }
+    RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+    COUNTERS = {
+        "/jax/compilation_cache/cache_hits": "jit_cache_hits",
+        "/jax/compilation_cache/cache_misses": "jit_cache_misses",
+    }
+
+    def __init__(self, tracer: Tracer):
+        self.tracer = tracer
+        registry = metrics.default_registry()
+        self._counters = {event: registry.counter(name)
+                          for event, name in self.COUNTERS.items()}
+        self._local = threading.local()
+
+    def on_duration(self, event: str, duration: float, **kwargs) -> None:
+        if not self.tracer.enabled:
+            return
+        if event == self.RETRIEVAL:
+            self._local.retrieval_s = duration
+            return
+        name = self.SPANS.get(event)
+        if name is None:
+            return
+        args = {"fun": kwargs.get("fun_name", "")}
+        if name == "jit.compile":
+            retrieval = getattr(self._local, "retrieval_s", None)
+            if retrieval is not None:
+                self._local.retrieval_s = None
+                name, args["retrieval_s"] = "jit.cache_load", retrieval
+        self.tracer.record_done(name, duration, **args)
+
+    def on_event(self, event: str, **kwargs) -> None:
+        counter = self._counters.get(event)
+        if counter is not None and self.tracer.enabled:
+            counter.inc()
+
+    def register(self) -> None:
+        monitoring.register_event_duration_secs_listener(self.on_duration)
+        monitoring.register_event_listener(self.on_event)
+
+
+JitListener(TRACER).register()
 
 
 def span(name: str, **args):

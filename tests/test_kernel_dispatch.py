@@ -257,3 +257,65 @@ def test_masked_attention_kernel_direct():
     ref = jnp.einsum("nqk,nkd->nqd", jax.nn.softmax(s, axis=-1), v)
     out = ops.masked_attention(q, k, v, mask, scale=float(scale))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def _holed_attn(S):
+    """Causal attention that hides key position 1 from every query:
+    position algebra, but not a band."""
+    def attn(qkv):
+        q, k, v = qkv
+        pos = jnp.arange(S)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+        ok = (pos[None, :] <= pos[:, None]) & (pos[None, :] != 1)
+        s = jnp.where(ok[None, None], s, -1e30)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+    return attn
+
+
+def _valid_attn(S):
+    """Causal attention masked by a validity vector the caller passes: a
+    mask no position algebra gives."""
+    def attn(qkv, valid):
+        q, k, v = qkv
+        pos = jnp.arange(S)
+        return L.gqa_attention(q, k, v, q_pos=pos, kv_pos=pos, causal=True,
+                               kv_valid=valid)
+
+    return attn
+
+
+@pytest.mark.parametrize("reason", [
+    None, "mask_mode_bool", "mask_eval_limit", "mask_not_position_algebra",
+    "mask_not_band",
+])
+def test_bool_mask_dispatch_counts_its_reason(reason, monkeypatch):
+    """Each attention dispatch is counted once, and each one that streams
+    a bool mask under exactly one ``kernel_dispatch_bool_mask.<reason>``."""
+    from repro.core import kernel_dispatch as KD
+
+    S = 64
+    qkv = _qkv(S=S, Kv=4)
+    fn, args, kw = _attn_fn(S, True), (qkv,), {}
+    if reason == "mask_mode_bool":
+        kw = {"mask_mode": "bool"}
+    elif reason == "mask_eval_limit":
+        monkeypatch.setattr(KD, "_MASK_EVAL_LIMIT", S * S - 1)
+    elif reason == "mask_not_position_algebra":
+        fn, args = _valid_attn(S), (qkv, jnp.arange(S) < S - 5)
+    elif reason == "mask_not_band":
+        fn = _holed_attn(S)
+    before = stats.snapshot()
+    on = _compile(fn, args, kernel_dispatch="on", **kw)
+    delta = stats.delta(before)
+    n = delta["kernel_dispatch_attention"]
+    assert n >= 1
+    counted = {k.rsplit(".", 1)[1]: v for k, v in delta.items()
+               if k.startswith("kernel_dispatch_bool_mask.") and v}
+    if reason is None:
+        assert counted == {} and delta["kernel_dispatch_computed_mask"] == n
+    else:
+        assert counted == {reason: n}
+        assert delta["kernel_dispatch_computed_mask"] == 0
+    np.testing.assert_allclose(np.asarray(on.fn(*args)),
+                               np.asarray(fn(*args)), atol=ATOL)

@@ -5,6 +5,8 @@ count independent of K (counter-asserted), stage rewrites compose on one
 graph, the emitted callable matches the unchunked function exactly, and
 ``Planned.lower()`` exposes the final rewritten jaxpr.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -238,3 +240,90 @@ def test_gradients_through_emitted_fn():
         np.testing.assert_allclose(
             np.asarray(g0[k]), np.asarray(g1[k]), atol=1e-3, rtol=1e-3
         )
+
+
+# ---------------------------------------------------------------------------
+# source lines and name stacks through the emit
+# ---------------------------------------------------------------------------
+
+def _op_locations(lowered_text):
+    """(op line, its location with every ``#locN`` reference expanded) of
+    each op in ``Lowered.as_text(debug_info=True)``."""
+    defs = dict(re.findall(r"^(#loc\d+) = (loc\(.*\))$", lowered_text, re.M))
+
+    def expand(text, depth=0):
+        if depth > 64:
+            return text
+        return re.sub(r"#loc\d+",
+                      lambda m: expand(defs.get(m.group(0), ""), depth + 1),
+                      text)
+
+    out = []
+    for line in lowered_text.splitlines():
+        m = re.search(r" = (\S+).* loc\((#loc\d+)\)$", line)
+        if m:
+            out.append((m.group(1), expand(m.group(2))))
+    return out
+
+
+def _mlp_scoped(w, x):
+    with jax.named_scope("mlp"):
+        h = x @ w
+        return jax.nn.silu(h) @ w.T
+
+
+def test_chunk_loop_ops_keep_model_scope_and_source_line():
+    line = _mlp_scoped.__code__.co_firstlineno + 2  # h = x @ w
+    w = jax.random.normal(jax.random.PRNGKey(0), (64, 256)) * 0.1
+    x = jax.random.normal(jax.random.PRNGKey(1), (512, 64))
+    compiled = autochunk(
+        _mlp_scoped, ChunkConfig(budget_ratio=0.3, weight_argnums=(0,)),
+        bucketer=None,
+    ).trace(w, x).search().compile()
+    lowered = jax.jit(compiled.fn).lower(w, x)
+    ops = _op_locations(lowered.as_text(debug_info=True))
+
+    dots = [loc for op, loc in ops if op == "stablehlo.dot_general"]
+    assert dots and all('loc("mlp/' in loc for loc in dots)
+    assert any(f'test_lowering.py":{line}:' in loc for loc in dots)
+    for op in ("stablehlo.dynamic_slice", "stablehlo.dynamic_update_slice"):
+        assert any('loc("autochunk.io/' in loc
+                   for name, loc in ops if name == op), op
+
+    # inside the chunk loop, and silu's ops (inlined from its nested jit)
+    # keep the caller's scope
+    hlo = lowered.compile().as_text()
+    body = r'op_name="[^"]*autochunk/while/body/[^"]*'
+    assert re.search(body + r'mlp/dot_general"', hlo)
+    assert re.search(body + r'mlp/(exp|logistic)"', hlo)
+    assert re.search(body + r'autochunk\.io/dynamic_update_slice"', hlo)
+
+
+def test_dispatched_kernel_keeps_its_own_source_line():
+    """A fused kernel runs under the replaced equation's scope but keeps
+    its ``pallas_call``'s source, by which the benchmark names kernels."""
+    from repro.models import layers as L
+
+    S = 64
+
+    def attn(qkv):
+        q, k, v = qkv
+        pos = jnp.arange(S)
+        return L.gqa_attention(q, k, v, q_pos=pos, kv_pos=pos, causal=True)
+
+    key = jax.random.PRNGKey(2)
+    qkv = tuple(jax.random.normal(jax.random.fold_in(key, i), (2, S, 4, 8))
+                for i in range(3))
+    compiled = autochunk(
+        attn, ChunkConfig(budget_ratio=0.3, kernel_dispatch="on"),
+        bucketer=None,
+    ).trace(qkv).search().compile()
+    ops = _op_locations(jax.jit(compiled.fn).lower(qkv).as_text(
+        debug_info=True))
+    kernel = [loc for _, loc in ops if 'computed_attention/' in loc]
+    assert kernel
+    assert all("chunked_attention.py" in loc and "layers.py" not in loc
+               for loc in kernel)
+    hlo = jax.jit(compiled.fn).lower(qkv).compile().as_text()
+    assert re.search(r'op_name="[^"]*autochunk/while/body/[^"]*attention/'
+                     r'[^"]*jit\(computed_attention\)', hlo)

@@ -476,3 +476,128 @@ def test_trace_export_cli_summary_and_merge(tmp_path, capsys):
         bad = tmp_path / "bad.json"
         bad.write_text('{"notatrace": 1}')
         trace_export.load_events(str(bad))
+
+
+# ---------------------------------------------------------------------------
+# jit compile stages, the profiler's timeline, the device's peak
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """JAX's persistent compilation cache in ``tmp_path``, caching every
+    executable; the process's own settings come back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = {"jax_compilation_cache_dir": str(tmp_path),
+            "jax_persistent_cache_min_compile_time_secs": 0,
+            "jax_persistent_cache_min_entry_size_bytes": 0}
+    old = {k: getattr(jax.config, k) for k in keys}
+    try:
+        for k, v in keys.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        yield tmp_path
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def _jit_probe(x):
+    return jnp.sin(x) * 3.0
+
+
+def _probe_spans(fun):
+    return [(s.name, s.args.get("retrieval_s") is not None)
+            for s in TRACER.spans() if s.name.startswith("jit.")
+            and s.args.get("fun") in (fun, f"jit({fun})")]
+
+
+def test_jit_stages_recorded_as_spans_and_cache_counted(persistent_cache,
+                                                        monkeypatch):
+    monkeypatch.setattr(TRACER, "enabled", True)
+    x = jnp.ones((8,))
+    before = stats.snapshot()
+    jax.jit(_jit_probe).lower(x).compile()   # compiled, written to the cache
+    jax.clear_caches()
+    jax.jit(_jit_probe).lower(x).compile()   # read back from the cache
+    delta = stats.delta(before)
+    assert _probe_spans("_jit_probe") == [
+        ("jit.trace", False), ("jit.lower", False), ("jit.compile", False),
+        ("jit.trace", False), ("jit.lower", False), ("jit.cache_load", True),
+    ]
+    assert delta["jit_cache_hits"] == 1 and delta["jit_cache_misses"] >= 1
+    load = [s for s in TRACER.spans("jit.cache_load")
+            if s.args["fun"] == "jit(_jit_probe)"][-1]
+    assert 0 < load.args["retrieval_s"] <= load.duration
+    assert load.parent is None or not load.parent.startswith("jit.")
+
+    monkeypatch.setattr(TRACER, "enabled", False)
+    before = stats.snapshot()
+    jax.clear_caches()
+    jax.jit(_jit_probe).lower(x).compile()
+    delta = stats.delta(before)
+    assert len(_probe_spans("_jit_probe")) == 6
+    assert delta["jit_cache_hits"] == delta["jit_cache_misses"] == 0
+
+
+def test_jit_spans_never_parent_a_compile_span(monkeypatch):
+    """``autochunk_compile_s`` sums the ``compile.*`` spans outside any
+    other: a compile span run inside a jit's trace stays top-level."""
+    monkeypatch.setattr(TRACER, "enabled", True)
+
+    def traced_fn(x):
+        with TRACER.span("compile.inside_probe"):
+            return jnp.cos(x)
+
+    jax.jit(traced_fn).lower(jnp.ones((4,))).compile()
+    (inner,) = TRACER.spans("compile.inside_probe")[-1:]
+    assert inner.parent is None and inner.depth == 0
+    (outer,) = [s for s in TRACER.spans("jit.trace")
+                if s.args["fun"] == "traced_fn"][-1:]
+    assert outer.start <= inner.start and inner.end <= outer.end
+
+
+def test_span_shows_on_the_profiler_timeline(tmp_path, monkeypatch):
+    import glob
+    import gzip
+
+    monkeypatch.setattr(TRACER, "enabled", True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TRACER.span("compile.profiled_probe"):
+            jnp.ones((4,)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.trace.json.gz"),
+                        recursive=True)
+    with gzip.open(path, "rt") as f:
+        events = json.load(f)["traceEvents"]
+    assert [e for e in events if e.get("name") == "compile.profiled_probe"
+            and e.get("ph") == "X"]
+
+
+@pytest.mark.parametrize("stats_dict,want", [
+    ({"bytes_in_use": 10, "peak_bytes_in_use": 100,
+      "peak_bytes_reserved": 40}, 140),
+    ({"bytes_in_use": 10, "peak_bytes_in_use": 100}, 100),
+    ({"bytes_in_use": 10}, None),
+])
+def test_device_peak_counts_reserved_bytes(stats_dict, want):
+    """On a TPU an executable's temporaries are reserved apart from the
+    buffers in use: the peak counts both."""
+
+    class FakeDevice:
+        def memory_stats(self):
+            return dict(stats_dict)
+
+    assert obs_accuracy.device_peak_bytes(FakeDevice()) == want
+
+
+def test_prometheus_names_keep_to_the_format():
+    reg = MetricsRegistry()
+    reg.counter("kernel_dispatch_bool_mask.kv_chunked").inc(2)
+    assert reg.to_prometheus().splitlines() == [
+        "# TYPE kernel_dispatch_bool_mask_kv_chunked counter",
+        "kernel_dispatch_bool_mask_kv_chunked 2",
+    ]

@@ -11,7 +11,8 @@ are pattern-matched against two shapes the fused Pallas kernels in
   softmax -> dot_general``, any operand order / GQA grouping / batch
   layout, with an arbitrary boolean mask (causal, sliding-window,
   padding...).  When the mask resolves to a *constant band* — causal and
-  sliding-window masks constant-fold into const bool arrays at trace time —
+  sliding-window masks are affine position algebra, read symbolically from
+  the mask's producing chain (``_symbolic_band``) —
   the site dispatches onto :func:`repro.kernels.ops.computed_attention`:
   the predicate is recomputed from block indices inside the kernel, no
   ``(Sq, Skv)`` mask array exists at any level, and fully-masked kv blocks
@@ -42,6 +43,8 @@ Counters: ``kernel_dispatch_hits`` / ``kernel_dispatch_misses`` /
 ``kernel_dispatch_errors`` / ``kernel_dispatch_computed_mask`` in
 ``core.stats`` make dispatch coverage observable in serve logs; an error is
 a matcher or validation that raised, whose body fell back to scan codegen.
+``kernel_dispatch_band.symbolic`` / ``.evaluated`` say which classifier
+found each computed-mask dispatch's band.
 """
 from __future__ import annotations
 
@@ -236,6 +239,11 @@ def _concrete_mask_value(
 ) -> Optional[np.ndarray]:
     """Concrete FULL-shape value behind a mask var, or None.
 
+    The fallback of the computed-mask classification: it runs only where
+    :func:`_symbolic_band` has no answer (a mask that is position algebra
+    but not a half-plane conjunction on ``j - a``: holes, ``or``, ``ne``,
+    ``min``/``max``...), because its cost and memory grow with Sq*Skv.
+
     Position masks are built from ``jnp.arange`` comparisons: the arange is
     an ``iota`` equation in the graph (usually outside the loop, its output
     sliced in) and the comparison chain sits in the body.  This evaluates
@@ -304,7 +312,10 @@ def _band_params(mask: np.ndarray) -> Optional[Tuple[int, int]]:
 
     Exact-reconstruction check: anything that is not a contiguous
     causal/sliding-window band (padding masks, block-sparse patterns)
-    returns None and keeps the boolean-mask kernel.
+    returns None and keeps the boolean-mask kernel.  Reads the mask that
+    :func:`_concrete_mask_value` materialises, so it runs only where
+    :func:`_symbolic_band` has no answer; the two agree wherever both
+    answer.
     """
     sq, skv = mask.shape
     counts = mask.sum(axis=1)
@@ -323,6 +334,275 @@ def _band_params(mask: np.ndarray) -> Optional[Tuple[int, int]]:
     ):
         return None
     return u, low
+
+
+# ---------------------------------------------------------------------------
+# Symbolic band classification
+# ---------------------------------------------------------------------------
+#
+# A causal or sliding-window mask is affine position algebra: each integer
+# intermediate of its chain is ``const + sum_d coef[d] * index[d]`` over the
+# intermediate's own axes, and each bool intermediate is a conjunction of
+# half-planes ``form >= 0``.  Carrying those forms through the producers
+# _concrete_mask_value walks classifies the band exactly, in time and memory
+# that do not depend on Sq or Skv.
+
+
+@dataclass(frozen=True)
+class _Form:
+    """Integer value ``const + sum_d coef[d] * index[d]`` over a var's axes;
+    an axis of size 1 carries coefficient 0."""
+
+    coef: Tuple[int, ...]
+    const: int
+
+    def span(self, shape) -> Tuple[int, int]:
+        """Least and greatest value over the var's index box."""
+        lo = hi = self.const
+        for c, n in zip(self.coef, shape):
+            lo += min(0, c * (n - 1))
+            hi += max(0, c * (n - 1))
+        return lo, hi
+
+
+@dataclass(frozen=True)
+class _Pred:
+    """Bool value: the conjunction of the half-planes ``form >= 0`` (none:
+    everywhere true), or everywhere false."""
+
+    halves: Tuple[_Form, ...] = ()
+    false: bool = False
+
+
+def _exact_in(dtype, span: Tuple[int, int]) -> bool:
+    """True when every integer in ``span`` is exact in ``dtype``: the
+    forms then give the very values the program computes."""
+    if jnp.issubdtype(dtype, jnp.integer):
+        info = jnp.iinfo(dtype)
+        return int(info.min) <= span[0] and span[1] <= int(info.max)
+    if jnp.issubdtype(dtype, jnp.floating):
+        lim = 2 ** (int(jnp.finfo(dtype).nmant) + 1)
+        return -lim <= span[0] and span[1] <= lim
+    return False
+
+
+def _remap(x, src: Sequence[Optional[int]], shape):
+    """``x`` over new axes: new axis o reads old axis ``src[o]`` (None: an
+    axis ``x`` does not vary along)."""
+    def one(f: _Form) -> _Form:
+        coef = tuple(
+            0 if s is None or n == 1 else f.coef[s] for s, n in zip(src, shape)
+        )
+        return _Form(coef, f.const)
+
+    if isinstance(x, _Form):
+        return one(x)
+    return _Pred(tuple(one(h) for h in x.halves), x.false)
+
+
+def _conj(halves: Sequence[_Form], shape) -> _Pred:
+    """The conjunction of ``halves``, with each half-plane that holds on the
+    whole index box dropped, and everywhere false if one holds nowhere."""
+    keep = []
+    for h in halves:
+        lo, hi = h.span(shape)
+        if hi < 0:
+            return _Pred(false=True)
+        if lo < 0:
+            keep.append(h)
+    return _Pred(tuple(keep))
+
+
+def _sym_leaf(val):
+    """A scalar literal or const as a rank-0 form or predicate, or None."""
+    if np.size(val) != 1:  # .size: a large const is never copied
+        return None
+    arr = np.asarray(val)
+    x = arr.reshape(()).item()
+    if arr.dtype == np.bool_:
+        return _Pred(false=not x)
+    if jnp.issubdtype(arr.dtype, jnp.integer) or (
+        jnp.issubdtype(arr.dtype, jnp.floating) and float(x).is_integer()
+    ):
+        return _Form((), int(x))
+    return None
+
+
+def _sym_mask(ctx: _BodyCtx, v, depth: int = 0):
+    """:class:`_Form` or :class:`_Pred` of ``v`` at its aval shape, or None
+    where the value is not affine position algebra.
+
+    Walks the producers :func:`_concrete_mask_value` walks, under the same
+    rule that position generators (``iota``, ``reshape``) are trusted only
+    outside the body, and builds no array.
+    """
+    if not is_var(v):
+        val = getattr(v, "val", None)
+        return None if val is None else _sym_leaf(val)
+    if v in ctx.consts:
+        return _sym_leaf(ctx.consts[v])
+    if depth > 24:
+        return None
+    shape = tuple(v.aval.shape)
+    dtype = v.aval.dtype
+    i = ctx.producer.get(v)
+    if i is not None:
+        e, allowed = ctx.eqns[i], _MASK_EVAL_ANY
+    else:
+        e, allowed = ctx.outer.get(v), _MASK_EVAL_OUTER
+    if e is None or e.primitive.name not in allowed:
+        return None
+    nm = e.primitive.name
+    if nm == "iota":
+        dim = int(e.params["dimension"])
+        f = _remap(_Form((1,), 0), [0 if d == dim else None
+                                    for d in range(len(shape))], shape)
+        return f if _exact_in(dtype, f.span(shape)) else None
+    ins = [_sym_mask(ctx, iv, depth + 1) for iv in e.invars]
+    if any(x is None for x in ins):
+        return None
+    if nm == "broadcast_in_dim":
+        src: List[Optional[int]] = [None] * len(shape)
+        for j, d in enumerate(e.params["broadcast_dimensions"]):
+            src[d] = j
+        return _remap(ins[0], src, shape)
+    if nm == "transpose":
+        return _remap(ins[0], list(e.params["permutation"]), shape)
+    if nm == "reshape":
+        # only a reshape that adds or drops unit dims keeps the axes apart
+        in_shape = tuple(e.invars[0].aval.shape)
+        ins_nu = [d for d, n in enumerate(in_shape) if n != 1]
+        out_nu = [d for d, n in enumerate(shape) if n != 1]
+        if e.params.get("dimensions") is not None or (
+            [in_shape[d] for d in ins_nu] != [shape[d] for d in out_nu]
+        ):
+            return None
+        src = [None] * len(shape)
+        for o, s in zip(out_nu, ins_nu):
+            src[o] = s
+        return _remap(ins[0], src, shape)
+    is_bool = dtype == np.bool_
+    if nm in ("convert_element_type", "stop_gradient"):
+        x = ins[0]
+        if isinstance(x, _Pred) != is_bool:
+            return None
+        if isinstance(x, _Form) and not _exact_in(dtype, x.span(shape)):
+            return None
+        return x
+    # elementwise: a rank-0 operand (a scalar literal) broadcasts
+    lifted = []
+    for x in ins:
+        if isinstance(x, _Form):
+            rank = len(x.coef)
+        else:
+            rank = len(x.halves[0].coef) if x.halves else 0
+        if rank == 0:
+            x = _remap(x, [None] * len(shape), shape)
+        elif rank != len(shape):
+            return None
+        lifted.append(x)
+    if is_bool:
+        if not all(isinstance(x, _Pred) for x in lifted):
+            return _sym_compare(nm, lifted, shape)
+        if nm == "not":
+            (x,) = lifted
+            if x.false or not x.halves:
+                return _Pred(false=not x.false)
+            if len(x.halves) != 1:
+                return None
+            h = x.halves[0]
+            return _conj([_Form(tuple(-c for c in h.coef), -h.const - 1)],
+                         shape)
+        if nm == "and":
+            a, b = lifted
+            if a.false or b.false:
+                return _Pred(false=True)
+            return _conj(a.halves + b.halves, shape)
+        return None
+    if len(lifted) != 2 or not all(isinstance(x, _Form) for x in lifted):
+        return None
+    a, b = lifted
+    if nm in ("add", "sub"):
+        sgn = 1 if nm == "add" else -1
+        f = _Form(tuple(p + sgn * q for p, q in zip(a.coef, b.coef)),
+                  a.const + sgn * b.const)
+    elif nm == "mul" and (not any(a.coef) or not any(b.coef)):
+        k, x = (a.const, b) if not any(a.coef) else (b.const, a)
+        f = _Form(tuple(k * c for c in x.coef), k * x.const)
+    else:
+        return None
+    return f if _exact_in(dtype, f.span(shape)) else None
+
+
+def _sym_compare(nm: str, ins, shape) -> Optional[_Pred]:
+    """``le``/``lt``/``ge``/``gt`` of two integer forms as a half-plane."""
+    if len(ins) != 2 or not all(isinstance(x, _Form) for x in ins):
+        return None
+    a, b = ins
+    if nm in ("le", "lt"):
+        a, b = b, a  # x <= y  is  y - x >= 0
+    elif nm not in ("ge", "gt"):
+        return None
+    off = 1 if nm in ("lt", "gt") else 0
+    diff = _Form(tuple(p - q for p, q in zip(a.coef, b.coef)),
+                 a.const - b.const - off)
+    return _conj([diff], shape)
+
+
+def _symbolic_band(
+    ctx: _BodyCtx, m_var, flip: bool, invert: bool, sq: int, skv: int
+) -> Optional[Tuple[int, int]]:
+    """The ``(U, L)`` :func:`_band_params` returns on the materialised mask,
+    read from the mask's position algebra alone; None where the algebra
+    gives no answer, and the numpy evaluation then decides.
+
+    Each half-plane of the mask's predicate must read ``k*(j - a) + c >= 0``
+    over the q index ``a`` and the kv index ``j``: it bounds ``d = j - a``
+    on one side, in integer arithmetic.  Their intersection is an interval
+    of ``d``, clipped to the ``d`` the (sq, skv) box holds; ``invert``
+    takes its complement where that is one interval.  A row left empty
+    returns None, as ``_band_params`` does.
+    """
+    shape = tuple(m_var.aval.shape)
+    if m_var.aval.dtype != np.bool_ or len(shape) != 2:
+        return None
+    qa, ka = (1, 0) if flip else (0, 1)
+    if (shape[qa], shape[ka]) != (sq, skv) or min(sq, skv) < 1:
+        return None
+    p = _sym_mask(ctx, m_var)
+    if not isinstance(p, _Pred):
+        return None
+    d_lo, d_hi = -(sq - 1), skv - 1
+    lo, hi = d_lo, d_hi
+    if p.false:
+        lo, hi = d_hi, d_lo - 1
+    for h in p.halves:
+        ca, cj = h.coef[qa], h.coef[ka]
+        # a size-1 axis has index 0: its coefficient is free
+        if sq == 1:
+            ca = -cj
+        if skv == 1:
+            cj = -ca
+        if cj != -ca or cj == 0:
+            return None  # depends on a or j alone, or on both unevenly
+        if cj > 0:
+            lo = max(lo, -(h.const // cj))
+        else:
+            hi = min(hi, h.const // -cj)
+    if invert:
+        if lo > hi:
+            lo, hi = d_lo, d_hi
+        elif lo == d_lo and hi == d_hi:
+            lo, hi = d_hi, d_lo - 1
+        elif lo == d_lo:
+            lo, hi = hi + 1, d_hi
+        elif hi == d_hi:
+            lo, hi = d_lo, lo - 1
+        else:
+            return None  # two intervals of d: rows with a hole
+    if lo > hi or hi < 0 or lo > skv - sq:
+        return None  # some row attends to nothing
+    return hi, -lo
 
 
 def _interior_is_private(ctx: _BodyCtx, interior: Set[int], at: int) -> bool:
@@ -659,17 +939,24 @@ def _try_attention(
     hd_sz = int(q_var.aval.shape[q_contract])
 
     # --- computed-mask classification --------------------------------------
-    # A 2-D mask whose concrete value is a contiguous band (causal /
-    # sliding-window) is replayed inside the kernel from block indices:
-    # no mask array is read, so the mask drops out of ``reads`` and its
-    # producing chain dies with it.  Requirements: the mask must evaluate
-    # concretely from position algebra (``_concrete_mask_value``), be
-    # chunked along its q axis (each chunk sees rows [i*c, i*c + c) of the
-    # full band — the builder rebuilds the global row offset from the loop
+    # A 2-D mask whose value is a contiguous band (causal / sliding-window)
+    # is replayed inside the kernel from block indices: no mask array is
+    # read, so the mask drops out of ``reads`` and its producing chain dies
+    # with it.  Requirements: the mask must be position algebra, be chunked
+    # along its q axis (each chunk sees rows [i*c, i*c + c) of the full
+    # band — the builder rebuilds the global row offset from the loop
     # index), and K must not be chunked along kv (column positions must
-    # stay global).  ``bool_reason`` names the requirement a bool-mask
-    # dispatch failed (counted per dispatch, ``kernel_dispatch_bool_mask.*``).
+    # stay global).  The band is read symbolically first
+    # (``_symbolic_band``: affine forms over the mask's two position
+    # indices, at a cost independent of Sq and Skv).  Only a mask that
+    # algebra gives no answer for is evaluated with numpy at full shape
+    # (``_concrete_mask_value`` + ``_band_params``), whose cost grows with
+    # Sq*Skv and is therefore capped by ``_MASK_EVAL_LIMIT``.
+    # ``band_source`` says which of the two classified the band (counted,
+    # ``kernel_dispatch_band.*``); ``bool_reason`` names the requirement a
+    # bool-mask dispatch failed (``kernel_dispatch_bool_mask.*``).
     band = None
+    band_source = None
     bool_reason = None
     if mask_mode == "bool":
         bool_reason = "mask_mode_bool"
@@ -680,6 +967,12 @@ def _try_attention(
     elif ctx.var_dim.get(k_var) == k_seq:
         bool_reason = "kv_chunked"
     else:
+        band_source = "symbolic"
+        band = _symbolic_band(
+            ctx, m_var, mask_flip, mask_invert, sq_full, skv_full
+        )
+    if band is None and bool_reason is None:
+        band_source = "evaluated"
         why: List[str] = []
         mval = _concrete_mask_value(ctx, m_var, why=why)
         if mval is None:
@@ -784,6 +1077,7 @@ def _try_attention(
     )
     meta = {
         "mask": mask_variant,
+        "band": band_source if band is not None else None,
         "bool_reason": bool_reason,
         "site": {
             "kind": "attention", "n": n_site, "sq": sq_full,
@@ -1148,6 +1442,8 @@ def dispatch_node(
     if n_computed:
         stats.bump("kernel_dispatch_computed_mask", n_computed)
     for m in attention:
+        if m.meta["band"] is not None:
+            stats.bump(f"kernel_dispatch_band.{m.meta['band']}")
         if m.meta["bool_reason"] is not None:
             stats.bump(f"kernel_dispatch_bool_mask.{m.meta['bool_reason']}")
     return len(records)

@@ -64,6 +64,12 @@ _PIPELINE_COUNTERS = (
     "kernel_dispatch_bool_mask.mask_eval_limit",
     "kernel_dispatch_bool_mask.mask_not_position_algebra",
     "kernel_dispatch_bool_mask.mask_not_band",
+    # which classifier gave each computed-mask dispatch its band: the
+    # symbolic one (affine position algebra, cost independent of the
+    # sequence length) or the numpy evaluation of the full mask it falls
+    # back to
+    "kernel_dispatch_band.symbolic",
+    "kernel_dispatch_band.evaluated",
     # kernel autotune (kernels.autotune): ``autotune_passes`` counts actual
     # candidate-grid evaluations (one per distinct site set per process —
     # warm plan replays and bucket hits restore the persisted KernelTuning

@@ -10,6 +10,9 @@ Runs in Pallas interpret mode on CPU — numerically exact but slow, which is
 why ``kernel_dispatch='auto'`` only turns the pass on under a TPU backend;
 tests force ``'on'``.
 """
+import math
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -300,7 +303,11 @@ def test_bool_mask_dispatch_counts_its_reason(reason, monkeypatch):
     if reason == "mask_mode_bool":
         kw = {"mask_mode": "bool"}
     elif reason == "mask_eval_limit":
+        # a causal mask classifies symbolically whatever its size; a mask
+        # the symbolic classifier has no answer for is evaluated, and that
+        # evaluation is what the limit refuses
         monkeypatch.setattr(KD, "_MASK_EVAL_LIMIT", S * S - 1)
+        fn = _holed_attn(S)
     elif reason == "mask_not_position_algebra":
         fn, args = _valid_attn(S), (qkv, jnp.arange(S) < S - 5)
     elif reason == "mask_not_band":
@@ -314,8 +321,185 @@ def test_bool_mask_dispatch_counts_its_reason(reason, monkeypatch):
                if k.startswith("kernel_dispatch_bool_mask.") and v}
     if reason is None:
         assert counted == {} and delta["kernel_dispatch_computed_mask"] == n
+        assert delta["kernel_dispatch_band.symbolic"] == n
     else:
         assert counted == {reason: n}
         assert delta["kernel_dispatch_computed_mask"] == 0
     np.testing.assert_allclose(np.asarray(on.fn(*args)),
                                np.asarray(fn(*args)), atol=ATOL)
+
+
+def test_causal_band_classifies_past_the_eval_limit(monkeypatch):
+    """Under a limit no full mask fits, a causal mask still dispatches onto
+    the position-computed kernel: its band is read symbolically, and the
+    numpy evaluation never runs."""
+    from repro.core import kernel_dispatch as KD
+
+    S = 64
+    monkeypatch.setattr(KD, "_MASK_EVAL_LIMIT", S * S - 1)
+    fn, qkv = _attn_fn(S, True), _qkv(S=S, Kv=2)
+    before = stats.snapshot()
+    on = _compile(fn, (qkv,), kernel_dispatch="on")
+    delta = stats.delta(before)
+    n = delta["kernel_dispatch_attention"]
+    assert n >= 1
+    assert delta["kernel_dispatch_computed_mask"] == n
+    assert delta["kernel_dispatch_band.symbolic"] == n
+    assert delta["kernel_dispatch_band.evaluated"] == 0
+    assert delta["kernel_dispatch_bool_mask.mask_eval_limit"] == 0
+    np.testing.assert_allclose(np.asarray(on.fn(qkv)), np.asarray(fn(qkv)),
+                               atol=ATOL)
+
+
+# --- symbolic band classification against the materialised mask ----------
+
+def _mask_ctx(mask_fn, *args):
+    """A mask function's graph as the classifiers see a mask's outer chain:
+    every equation an outer producer, so ``iota`` counts from 0 to S-1."""
+    from repro.core import graph as G
+    from repro.core import kernel_dispatch as KD
+
+    g, _ = G.trace(mask_fn, args, weight_argnums=())
+    ctx = KD._BodyCtx(eqns=[], outer=KD._outer_producers(g), consts=g.consts)
+    return ctx, g.outvars[0]
+
+
+def _band_mask(sq, skv, *, causal=True, window=None, q_off=0):
+    def mask():
+        return L.attention_scores_mask(
+            jnp.arange(sq) + q_off, jnp.arange(skv), causal=causal,
+            window=window)
+
+    return mask
+
+
+def _pos(sq, skv):
+    a = jnp.arange(sq)[:, None]
+    j = jnp.arange(skv)[None, :]
+    return a, j
+
+
+_BAND_CASES = {
+    # name: (mask fn, flip, invert)
+    "causal": (_band_mask(48, 48), False, False),
+    "full": (_band_mask(48, 48, causal=False), False, False),
+    "sliding_window": (_band_mask(48, 48, window=16), False, False),
+    "window_only": (_band_mask(40, 40, causal=False, window=7), False, False),
+    "causal_sq_lt_skv": (_band_mask(24, 40, q_off=16), False, False),
+    "window_sq_lt_skv": (_band_mask(24, 40, window=9, q_off=16), False, False),
+    "causal_sq_gt_skv": (_band_mask(40, 24), False, False),
+    "masked_true_convention": (
+        lambda: (lambda a, j: j > a)(*_pos(32, 32)), False, True),
+    "masked_true_sq_lt_skv": (
+        lambda: (lambda a, j: j > a + 16)(*_pos(24, 40)), False, True),
+    "masked_true_below": (
+        lambda: (lambda a, j: j <= a - 4)(*_pos(32, 32)), False, True),
+    "not_of_half_plane": (
+        lambda: (lambda a, j: ~(j > a))(*_pos(32, 32)), False, False),
+    "one_corner_cut": (
+        lambda: (lambda a, j: j <= a + 30)(*_pos(32, 32)), False, False),
+    "scaled_lower_bound": (
+        lambda: (lambda a, j: 2 * j + 3 >= 2 * a)(*_pos(24, 24)),
+        False, False),
+    "transposed_kv_q": (lambda: _band_mask(32, 40, q_off=8)().T, True, False),
+    "kv_q_built": (
+        lambda: (lambda a, j: a.T <= j.T)(*_pos(20, 30)), True, False),
+    "strictly_above": (lambda: (lambda a, j: j > a)(*_pos(8, 16)),
+                       False, False),
+    "scaled_offset": (
+        lambda: (lambda a, j: 2 * j <= 2 * a + 3)(*_pos(24, 24)),
+        False, False),
+    "float_positions": (
+        lambda: (lambda a, j: j.astype(jnp.float32) - 4.0
+                 <= a.astype(jnp.float32))(*_pos(24, 24)), False, False),
+    "reshape_unit_dims": (
+        lambda: jnp.arange(16).reshape(16, 1) >= jnp.arange(16)[None, :],
+        False, False),
+    "single_row": (lambda: (lambda a, j: j <= a + 10)(*_pos(1, 24)),
+                   False, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_BAND_CASES))
+def test_symbolic_band_matches_materialised_mask(name):
+    """The symbolic classifier gives exactly the ``(U, L)`` that
+    ``_band_params`` reads off the materialised mask."""
+    from repro.core import kernel_dispatch as KD
+
+    mask_fn, flip, invert = _BAND_CASES[name]
+    ctx, m_var = _mask_ctx(mask_fn)
+    m = np.asarray(mask_fn())
+    m2 = m.T if flip else m
+    if invert:
+        m2 = ~m2
+    sq, skv = m2.shape
+    want = KD._band_params(m2)
+    assert want is not None
+    assert KD._symbolic_band(ctx, m_var, flip, invert, sq, skv) == want
+
+
+_NOT_BAND_CASES = {
+    "holed": lambda: (lambda a, j: (j <= a) & (j != 1))(*_pos(32, 32)),
+    "or_of_two_bands": lambda: (
+        lambda a, j: (j <= a - 8) | (j >= a))(*_pos(32, 32)),
+    "row_only_empties_rows": lambda: jnp.ones((32, 32), bool)
+    & (jnp.arange(32)[:, None] < 32 - 5),
+    "two_intervals_inverted": lambda: (
+        lambda a, j: (j <= a + 2) & (j >= a - 2))(*_pos(32, 32)),
+    "rows_past_the_last_key": lambda: (
+        lambda a, j: j >= a + 3)(*_pos(32, 32)),
+    "rows_before_the_first_key": lambda: (
+        lambda a, j: j <= a - 3)(*_pos(32, 32)),
+    "uneven_slopes": lambda: (lambda a, j: 2 * j <= a)(*_pos(32, 32)),
+    "nowhere_true": lambda: (
+        lambda a, j: (j <= a) & (a - j >= 64))(*_pos(32, 32)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NOT_BAND_CASES) + ["kv_valid"])
+def test_symbolic_band_refuses_non_bands(name):
+    """Masks that are not a band, or not position algebra, get no symbolic
+    answer (the numpy evaluation then decides)."""
+    from repro.core import kernel_dispatch as KD
+
+    S = 32
+    invert = name == "two_intervals_inverted"
+    if name == "kv_valid":
+        def mask_fn(valid):
+            a, j = _pos(S, S)
+            return (j <= a) & valid[None, :]
+
+        args = (jnp.arange(S) < S - 5,)
+    else:
+        mask_fn, args = _NOT_BAND_CASES[name], ()
+    ctx, m_var = _mask_ctx(mask_fn, *args)
+    assert KD._symbolic_band(ctx, m_var, False, invert, S, S) is None
+    m = np.asarray(mask_fn(*args))
+    assert KD._band_params(~m if invert else m) is None
+
+
+def test_symbolic_band_full_size_builds_no_square(monkeypatch):
+    """The 32,768^2 causal chain of ``attention_scores_mask`` classifies
+    from its jaxpr alone, without an array of the mask's size."""
+    from repro.core import kernel_dispatch as KD
+
+    S = 32768
+    limit = 1 << 20
+    real_broadcast, real_arange = np.broadcast_to, np.arange
+
+    def small_broadcast(x, shape, *a, **k):
+        assert math.prod(shape) <= limit, shape
+        return real_broadcast(x, shape, *a, **k)
+
+    def small_arange(*a, **k):
+        out = real_arange(*a, **k)
+        assert out.size <= limit, out.size
+        return out
+
+    ctx, m_var = _mask_ctx(_band_mask(S, S))
+    monkeypatch.setattr(np, "broadcast_to", small_broadcast)
+    monkeypatch.setattr(np, "arange", small_arange)
+    t0 = time.perf_counter()
+    band = KD._symbolic_band(ctx, m_var, False, False, S, S)
+    assert time.perf_counter() - t0 < 1.0
+    assert band == (0, S - 1)

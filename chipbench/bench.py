@@ -2,13 +2,15 @@
 
     python chipbench/bench.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Everything that belongs to one cell is data, found by name:
+Everything that belongs to one cell is found by name:
 ``workloads/<cell>.json`` names its configuration
 (``configs/<config>.json``), its traffic mix (``traffic/<mix>.json``, whose
 ``kind`` picks the driver ``kinds/<kind>.py``) and the numbers compared
-for ``correct`` with their limits.  ``BENCHMARK.json`` at the checkout's
-root says which metrics the cell reports; each per-layer metric is read by
-``metrics/<metric>.py``.
+for ``correct`` with their limits.  The configuration's ``reference``
+names its architecture module, ``references/<reference>.py``: the weights,
+the plain reference and the operation counts (``CONTRACT``).
+``BENCHMARK.json`` at the checkout's root says which metrics the cell
+reports; each per-layer metric is read by ``metrics/<metric>.py``.
 
 With ``--trace 0`` the last line of standard output carries the cell's
 end-to-end metrics; with ``--trace 1`` it carries its per-layer metrics,
@@ -95,23 +97,61 @@ def cell_files(name: str, root: Path = HERE) -> dict:
     }
 
 
+# What an architecture module (``references/<name>.py``) gives, each a
+# function of the configuration file's ``sizes``:
+#   make(sizes, seed, spec): the program's parameter tree on the device,
+#       in the layout and dtypes of ``spec`` (``ShapeDtypeStruct``s);
+#       raises where ``spec`` differs
+#   last_logits(sizes, seed, tokens, mode=, dtype=): the reference's
+#       next-token logits; ``mode="fp8"`` is the control
+#   position_stats(sizes, seed, tokens, positions, lookup, mode=, dtype=):
+#       the largest logit, its token, and the logits of ``lookup`` at
+#       each position
+#   prefill_flops(sizes, seq_len): model FLOPs of one prefill
+#   prefill_attention(sizes, seq_len): (flops, bytes) of its attention
+#   tokens_flops(sizes, q_len, kv_len, unembedded=): model FLOPs of new
+#       tokens at the end of a context
+#   unembed_flops(sizes): FLOPs of one position's unembedding
+#   paged_step(sizes, rows): (flops, bytes) of the paged kernel over one
+#       engine step of every layer
+CONTRACT = ("make", "last_logits", "position_stats", "prefill_flops",
+            "prefill_attention", "tokens_flops", "unembed_flops",
+            "paged_step")
+# ``sizes`` keys named otherwise in the program's configuration
+PROGRAM_NAMES = {"head_dim": "hd"}
+
+
+def reference(conf: dict, root: Path = HERE):
+    """The architecture module a configuration file names.  Raises where
+    it is missing or lacks a function of ``CONTRACT``."""
+    path = root / "references" / f"{conf['reference']}.py"
+    if not path.is_file():
+        raise ValueError(f"no architecture module {path}")
+    mod = load_module(path)
+    missing = [f for f in CONTRACT if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"{path} lacks {missing}")
+    return mod
+
+
 def model_config(conf: dict):
     """The program's configuration for a configuration file: the registry
-    entry with the file's overrides, checked against the file's sizes."""
+    entry with the file's overrides, checked against every one of the
+    file's sizes."""
     from repro.configs import get_config
 
     cfg = get_config(conf["registry"]).with_(**conf.get("overrides", {}))
-    have = {
-        "n_layers": cfg.n_layers, "d_model": cfg.d_model,
-        "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-        "head_dim": cfg.hd, "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size,
-        "act": cfg.act, "rope_theta": cfg.rope_theta, "norm": cfg.norm,
-        "dtype": cfg.dtype, "sliding_window": cfg.sliding_window,
-        "tie_embeddings": cfg.tie_embeddings, "family": cfg.family,
-    }
-    sizes = conf["sizes"]
-    wrong = {k: (have[k], v) for k, v in sizes.items()
-             if k in have and have[k] != v}
+    absent = object()
+    unknown, wrong = [], {}
+    for key, want in conf["sizes"].items():
+        have = getattr(cfg, PROGRAM_NAMES.get(key, key), absent)
+        if have is absent:
+            unknown.append(key)
+        elif have != want:
+            wrong[key] = (have, want)
+    if unknown:
+        raise ValueError(f"{conf['registry']}: the program's configuration"
+                         f" has no {unknown}")
     if wrong:
         raise ValueError(f"{conf['registry']}: the program's configuration"
                          f" differs from the file (program, file): {wrong}")
@@ -127,13 +167,15 @@ def cell_metrics(bench: dict, cell: str, group: str) -> list:
 class Ctx:
     """What a driver (``kinds/<kind>.py``) is given for one run."""
 
-    def __init__(self, args, files, cfg, devices):
+    def __init__(self, args, files, cfg, devices, reference):
         self.seed = args.seed
         self.seconds = args.seconds
         self.trace = bool(args.trace)
         self.work, self.mix = files["work"], files["mix"]
         self.sizes = dict(files["config"]["sizes"])
         self.cfg = cfg
+        # the configuration's architecture module (``CONTRACT``)
+        self.reference = reference
         self.devices = devices
         self.trace_dir = CACHE / "trace"
         self.t_start = T_START
@@ -243,6 +285,7 @@ def run(args, root: Path = HERE, bench_file: Path = ROOT / "BENCHMARK.json",
     if args.workload not in cells:
         raise SystemExit(f"unknown workload {args.workload!r}")
     files = cell_files(args.workload, root)
+    ref = reference(files["config"], root)
 
     import jax
 
@@ -253,7 +296,7 @@ def run(args, root: Path = HERE, bench_file: Path = ROOT / "BENCHMARK.json",
     devices = require_chip(cells[args.workload]["chips"])
     dev = devices[0]
     cfg = model_config(files["config"])
-    ctx = Ctx(args, files, cfg, devices)
+    ctx = Ctx(args, files, cfg, devices, ref)
     ctx.control = control
     driver = load_module(root / "kinds" / f"{files['mix']['kind']}.py")
     res = driver.run(ctx)
@@ -264,7 +307,7 @@ def run(args, root: Path = HERE, bench_file: Path = ROOT / "BENCHMARK.json",
     out = {"correct": res["correct"], "attempted": res["attempted"],
            "failed": res["failed"]}
     if ctx.trace:
-        data = dict(res["layer_data"], sizes=ctx.sizes,
+        data = dict(res["layer_data"], sizes=ctx.sizes, reference=ref,
                     device_kind=dev.device_kind,
                     compile_spans=top_level_compile_spans())
         summary = data.get("trace")

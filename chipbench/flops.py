@@ -7,9 +7,11 @@ GQA sizes.  A kernel that skips masked blocks, or reads K/V once per KV
 head, therefore gains honestly, and every implementation is held to the
 same count.
 
-``sizes`` is a configuration file's ``sizes`` mapping (see
-``configs/*.json``): n_layers, d_model, n_heads, n_kv_heads, head_dim,
-d_ff, vocab_size, act.
+``attended_pairs`` and ``attention`` serve every architecture.  The rest
+are the dense GQA decoder's counts, which ``references/dense.py`` gives
+the readers; another architecture's module gives its own.  ``sizes`` is
+a configuration file's ``sizes`` mapping (see ``configs/*.json``):
+n_layers, d_model, n_heads, n_kv_heads, head_dim, d_ff, vocab_size, act.
 """
 from __future__ import annotations
 

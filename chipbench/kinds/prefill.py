@@ -12,7 +12,8 @@ traced run traces the window's first prefill alone, before the others are
 dispatched.
 
 ``correct``: the next-token logits of one prefill drawn from the seed,
-against the plain reference on the same prompt (``logit_err``: largest
+against the plain reference of the configuration's architecture module
+(``ctx.reference``) on the same prompt (``logit_err``: largest
 absolute gap over the vocabulary, over the reference's largest absolute
 logit).  With ``ctx.control`` the float8 reference's logits are judged by
 the same comparison in the program's place.
@@ -24,7 +25,6 @@ import time
 import numpy as np
 
 import traffic
-import weights
 
 
 def run(ctx) -> dict:
@@ -35,11 +35,11 @@ def run(ctx) -> dict:
     import bench
 
     ctx.install_plan_cache()
-    work, mix, sizes = ctx.work, ctx.mix, ctx.sizes
+    work, mix, sizes, ref = ctx.work, ctx.mix, ctx.sizes, ctx.reference
     cfg = ctx.cfg.with_(autochunk_budget=float(work["autochunk_budget_bytes"]))
     V, S = sizes["vocab_size"], mix["prompt_len"]
 
-    params = weights.make(sizes, ctx.seed, M.param_specs(cfg))
+    params = ref.make(sizes, ctx.seed, M.param_specs(cfg))
     host_prompts = [traffic.token_ids(ctx.seed, f"prompt{i}", S, V)
                     for i in range(mix["distinct_prompts"])]
     prompts = [jax.device_put(p[None]) for p in host_prompts]
@@ -96,8 +96,6 @@ def run(ctx) -> dict:
             f" {pick} (prompt {idx})")
     del params, prompts, compiled, outs
     bench.free_device_state()
-
-    from references import dense as ref
 
     t = time.perf_counter()
     with ctx.annotate("bench.reference"):

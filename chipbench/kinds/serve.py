@@ -15,7 +15,8 @@ right after the ``step()`` that produced them.
 
 ``correct``: a sample of the window's finished requests drawn from the
 seed, the longest among them, with at least ``check_tokens`` served tokens
-in all; the plain reference runs once over each prompt with its served
+in all; the plain reference of the configuration's architecture module
+(``ctx.reference``) runs once over each prompt with its served
 tokens, and ``token_gap`` is the widest gap by which a served token's
 logit lies below the reference's largest logit at that position.  With
 ``ctx.control`` the tokens the float8 reference puts first at those
@@ -28,7 +29,6 @@ import time
 import numpy as np
 
 import traffic
-import weights
 
 
 class _StepLog:
@@ -83,7 +83,7 @@ def build(ctx, num_pages=None):
     from repro.serving import PagedServeEngine, Request
 
     work = ctx.work
-    params = weights.make(ctx.sizes, ctx.seed, M.param_specs(ctx.cfg))
+    params = ctx.reference.make(ctx.sizes, ctx.seed, M.param_specs(ctx.cfg))
     engine = PagedServeEngine(
         ctx.cfg, params, max_seqs=work["max_seqs"], max_len=work["max_len"],
         page_size=work["page_size"],
@@ -230,7 +230,8 @@ def run(ctx) -> dict:
 
     t = time.perf_counter()
     with ctx.annotate("bench.reference"):
-        gap, ctl = compare(sizes, ctx.seed, checked, control=ctx.control)
+        gap, ctl = compare(ctx.reference, sizes, ctx.seed, checked,
+                           control=ctx.control)
     n_tok = sum(len(s) for _, s in checked)
     ctx.log(f"reference over {len(checked)} requests, {n_tok} served"
             f" tokens: {time.perf_counter() - t:.2f}s")
@@ -279,12 +280,11 @@ def judge(checked: bool, gap: float, unfinished: int, limits):
     return bool(ok), [("token_gap", gap, limit), ("unfinished", unfinished, 0)]
 
 
-def compare(sizes, seed, checked, control=False):
-    """Widest reference gap below the best logit over the served tokens
-    of ``checked`` [(prompt, served)], and with ``control`` the same for
-    the tokens the float8 control puts first at those positions."""
-    from references import dense as ref
-
+def compare(ref, sizes, seed, checked, control=False):
+    """Widest gap of the reference ``ref`` below its best logit over the
+    served tokens of ``checked`` [(prompt, served)], and with ``control``
+    the same for the tokens the float8 control puts first at those
+    positions."""
     gap = 0.0
     ctl = 0.0 if control else None
     for prompt, served in checked:

@@ -1,11 +1,11 @@
 """Share of its roofline that the prefill's attention kernels reached.
 
 The least time is the larger of causal attention's useful FLOPs over the
-bf16 peak and its Q, K, V and O bytes over the HBM bandwidth
-(``flops.prefill_attention``), for the prefills in the trace; the time is
-the device time of the attention kernels' events there.
+bf16 peak and its Q, K, V and O bytes over the HBM bandwidth (the
+architecture module's ``prefill_attention``), for the prefills in the
+trace; the time is the device time of the attention kernels' events
+there.
 """
-import flops
 import peaks
 import trace_reduce
 
@@ -18,6 +18,6 @@ def read(data):
     if kernel_s <= 0:
         return None
     p = data["prefill"]
-    f, b = flops.prefill_attention(data["sizes"], p["seq_len"])
+    f, b = data["reference"].prefill_attention(data["sizes"], p["seq_len"])
     least = peaks.roofline_s(f, b, data["device_kind"]) * p["traced"]
     return 100 * least / kernel_s

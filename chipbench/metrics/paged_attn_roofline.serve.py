@@ -1,11 +1,10 @@
 """Share of its roofline that the paged attention kernel reached.
 
 The least time is the larger of the useful causal FLOPs and the Q, K, V
-and O bytes (``flops.paged_step``) of every row of every engine step in
-the traced window, over the chip's peaks; the time is the device time of
-the paged kernel's events in that window.
+and O bytes (the architecture module's ``paged_step``) of every row of
+every engine step in the traced window, over the chip's peaks; the time
+is the device time of the paged kernel's events in that window.
 """
-import flops
 import peaks
 import trace_reduce
 
@@ -20,7 +19,7 @@ def read(data):
         return None
     f = b = 0
     for s in steps:
-        sf, sb = flops.paged_step(data["sizes"], s["rows"])
+        sf, sb = data["reference"].paged_step(data["sizes"], s["rows"])
         f += sf
         b += sb
     return 100 * peaks.roofline_s(f, b, data["device_kind"]) / kernel_s

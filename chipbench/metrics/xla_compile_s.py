@@ -7,8 +7,8 @@ the AutoChunk compile (the top-level ``compile.*`` spans run while the
 model is traced), the outermost such when jits nest.  Its compile spans
 are those named ``jit(<traced fun>)`` that start after that trace ends:
 the kernel autotuner's candidates, compiled inside the trace, and the
-benchmark's own jitted functions (``weights.py``, ``references/dense.py``,
-other names) are left out."""
+benchmark's own jitted functions (the architecture module's weights and
+reference, other names) are left out."""
 from repro.obs import tracing
 
 

@@ -1,7 +1,23 @@
-"""Plain reference of a dense GQA decoder, in float32, one layer at a time.
+"""The dense GQA decoder: its weights, its plain reference in float32,
+one layer at a time, and its operation counts.
 
-It follows the layer equations the configuration files state, and
-imports nothing of the program:
+A configuration file whose ``"reference"`` is ``dense`` is run with this
+module (``bench.reference``): ``make`` builds the program's parameter
+tree, ``last_logits`` and ``position_stats`` are the reference that
+decides ``correct``, and the counts (``prefill_flops``,
+``prefill_attention``, ``tokens_flops``, ``unembed_flops``,
+``paged_step``, from ``flops.py``) are what the readers divide by.
+
+Weights (``weights.py``'s hash of each element's place): uniform with the
+standard deviation of the usual initialisation, 1/sqrt(fan_in) for
+matrices and 0.1 for norm scales (applied as ``1 + w``).  ``layer(sizes,
+weights.layer_key(weights.base_key(seed), i))`` gives the values that
+``make`` put in layer ``i``, so the reference draws one layer again, bit
+for bit, without the rest.  The vocabulary rows beyond ``vocab_size``
+that the program's layout pads to are zero.
+
+The reference follows the layer equations the configuration files state,
+and imports nothing of the program:
 
     x = x + Wo . attn(rope(Wq . n1(x)), rope(Wk . n1(x)), Wv . n1(x))
     x = x + Wout . (up * silu(gate)),  [up | gate] = Win . n2(x)
@@ -10,8 +26,8 @@ imports nothing of the program:
 RoPE rotates the two halves of each head (theta from the file), attention
 is causal with scale 1/sqrt(head_dim), and query head h reads KV head
 h // (n_heads / n_kv_heads).  The weights are drawn again from the seed
-(``weights.layer``), rounded to the served dtype and widened to float32,
-so nothing the program made is read.
+(``layer``), rounded to the served dtype and widened to float32, so
+nothing the program made is read.
 
 ``mode="f32"`` is the reference: every matmul in float32 at highest
 precision.  ``mode="fp8"`` is the control: every matmul's operands are
@@ -34,6 +50,9 @@ import numpy as np
 from jax import lax
 
 import weights as W
+# the counts that the readers divide by: the dense ones of flops.py
+from flops import paged_step, prefill_attention, tokens_flops, unembed_flops
+from flops import prefill as prefill_flops
 
 HIGHEST = lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -41,6 +60,86 @@ F8_MAX = 448.0
 Q_BLOCK = 256
 ROW_BLOCK = 2048
 LOGIT_BLOCK = 256
+# tags of the leaves outside the layer stack (layers use 0..L-1)
+EMBED, LM_HEAD, FINAL_NORM = 1 << 20, (1 << 20) + 1, (1 << 20) + 2
+
+
+def gated(sizes: dict) -> bool:
+    return sizes["act"] in ("swiglu", "geglu")
+
+
+def layer(sizes: dict, key) -> dict:
+    """One layer's leaves in float32, in the program's layout: q/k/v/o
+    projections ``x @ w``, and an MLP whose ``w_in`` holds the up half and
+    then the gate half."""
+    d, hd, f = sizes["d_model"], sizes["head_dim"], sizes["d_ff"]
+    H, Kv = sizes["n_heads"], sizes["n_kv_heads"]
+    k = [W.fold(key, j) for j in range(8)]
+    return {
+        "ln1": {"w": W.uniform(k[0], (d,), 0.1)},
+        "ln2": {"w": W.uniform(k[1], (d,), 0.1)},
+        "attn": {
+            "wq": W.uniform(k[2], (d, H * hd), 1 / math.sqrt(d)),
+            "wk": W.uniform(k[3], (d, Kv * hd), 1 / math.sqrt(d)),
+            "wv": W.uniform(k[4], (d, Kv * hd), 1 / math.sqrt(d)),
+            "wo": W.uniform(k[5], (H * hd, d), 1 / math.sqrt(H * hd)),
+        },
+        "mlp": {
+            "w_in": W.uniform(k[6], (d, (2 if gated(sizes) else 1) * f),
+                              1 / math.sqrt(d)),
+            "w_out": W.uniform(k[7], (f, d), 1 / math.sqrt(f)),
+        },
+    }
+
+
+def embedding(sizes: dict, base):
+    return W.uniform(W.fold(base, EMBED),
+                     (sizes["vocab_size"], sizes["d_model"]),
+                     1 / math.sqrt(sizes["d_model"]))
+
+
+def lm_head(sizes: dict, base):
+    return W.uniform(W.fold(base, LM_HEAD),
+                     (sizes["d_model"], sizes["vocab_size"]),
+                     1 / math.sqrt(sizes["d_model"]))
+
+
+def final_norm(sizes: dict, base):
+    return W.uniform(W.fold(base, FINAL_NORM), (sizes["d_model"],), 0.1)
+
+
+@partial(jax.jit, static_argnums=(0, 2, 3))
+def _make(frozen, base, vocab_rows: int, dtype: str):
+    sizes = dict(frozen)
+    pad = vocab_rows - sizes["vocab_size"]
+    keys = jax.vmap(lambda i: W.layer_key(base, i))(
+        jnp.arange(sizes["n_layers"], dtype=jnp.uint32))
+    blocks = jax.vmap(lambda k: jax.tree.map(lambda x: x.astype(dtype),
+                                             layer(sizes, k)))(keys)
+    return {
+        "embed": {
+            "embedding": jnp.pad(embedding(sizes, base).astype(dtype),
+                                 ((0, pad), (0, 0))),
+            "lm_head": jnp.pad(lm_head(sizes, base).astype(dtype),
+                               ((0, 0), (0, pad))),
+        },
+        "final_norm": {"w": final_norm(sizes, base).astype(dtype)},
+        "blocks": blocks,
+    }
+
+
+def make(sizes: dict, seed: int, spec):
+    """All weights in one jitted call, in the dtype and padded layout of
+    ``spec``, the program's parameter shapes (``ShapeDtypeStruct`` tree).
+
+    Raises where the program expects another tree, shape or dtype.
+    """
+    vocab_rows = spec["embed"]["embedding"].shape[0]
+    dtype = str(spec["embed"]["embedding"].dtype)
+    frozen, base = W.freeze(sizes), W.base_key(seed)
+    W.check(jax.eval_shape(lambda b: _make(frozen, b, vocab_rows, dtype),
+                           base), spec)
+    return _make(frozen, base, vocab_rows, dtype)
 
 
 def _qdq(x):
@@ -96,7 +195,7 @@ def _row_map(fn, x, block):
 @partial(jax.jit, static_argnums=(0, 1, 2))
 def _layer(frozen, mode, dtype, key, x):
     s = dict(frozen)
-    lw = _served(W.layer(s, key), dtype)
+    lw = _served(layer(s, key), dtype)
     T = x.shape[0]
     H, Kv, hd = s["n_heads"], s["n_kv_heads"], s["head_dim"]
     G = H // Kv
@@ -147,7 +246,7 @@ def _layer(frozen, mode, dtype, key, x):
 
 @partial(jax.jit, static_argnums=(0, 1))
 def _embed(frozen, dtype, base, tokens):
-    table = _served(W.embedding(dict(frozen), base), dtype)
+    table = _served(embedding(dict(frozen), base), dtype)
     return jnp.take(table, tokens, axis=0)
 
 
@@ -156,8 +255,8 @@ def _logit_stats(frozen, mode, dtype, base, h, lookup):
     """Per row of h (P, d): largest logit, its token, and the logits of
     the tokens in ``lookup`` (P, k)."""
     s = dict(frozen)
-    head = _served(W.lm_head(s, base), dtype)
-    w = _served(W.final_norm(s, base), dtype)
+    head = _served(lm_head(s, base), dtype)
+    w = _served(final_norm(s, base), dtype)
 
     def one(blk):
         hb, lb = blk
@@ -178,13 +277,9 @@ def _logit_stats(frozen, mode, dtype, base, h, lookup):
 @partial(jax.jit, static_argnums=(0, 1, 2))
 def _full_logits(frozen, mode, dtype, base, h):
     s = dict(frozen)
-    head = _served(W.lm_head(s, base), dtype)
-    w = _served(W.final_norm(s, base), dtype)
+    head = _served(lm_head(s, base), dtype)
+    w = _served(final_norm(s, base), dtype)
     return _mm(mode, "pd,dv->pv", _norm(h, w), head)
-
-
-def _frozen(sizes):
-    return tuple(sorted(sizes.items()))
 
 
 def hidden(sizes: dict, seed: int, tokens, *, mode: str = "f32",
@@ -195,7 +290,7 @@ def hidden(sizes: dict, seed: int, tokens, *, mode: str = "f32",
     lengths share compiled programs; causality keeps the padding out of
     every real position.
     """
-    frozen = _frozen(sizes)
+    frozen = W.freeze(sizes)
     tokens = np.asarray(tokens, np.int32)
     T = len(tokens)
     Tp = _blocks(T, bucket) * bucket
@@ -210,7 +305,8 @@ def last_logits(sizes: dict, seed: int, tokens, *, mode: str = "f32",
                 dtype: str = "bfloat16") -> np.ndarray:
     """Next-token logits (vocab_size,) float32 after ``tokens``."""
     h = hidden(sizes, seed, tokens, mode=mode, dtype=dtype)
-    out = _full_logits(_frozen(sizes), mode, dtype, W.base_key(seed), h[-1:])
+    out = _full_logits(W.freeze(sizes), mode, dtype, W.base_key(seed),
+                       h[-1:])
     return np.asarray(out[0], np.float32)
 
 
@@ -222,6 +318,6 @@ def position_stats(sizes: dict, seed: int, tokens, positions, lookup, *,
     h = hidden(sizes, seed, tokens, mode=mode, dtype=dtype)
     pos = jnp.asarray(np.asarray(positions, np.int32))
     best, top, got = _logit_stats(
-        _frozen(sizes), mode, dtype, W.base_key(seed), h[pos],
+        W.freeze(sizes), mode, dtype, W.base_key(seed), h[pos],
         jnp.asarray(np.asarray(lookup, np.int32)))
     return np.asarray(best), np.asarray(top), np.asarray(got)

@@ -82,7 +82,8 @@ def main(argv=None) -> int:
     devices = bench.require_chip(1)
     cfg = bench.model_config(files["config"])
     ns = argparse.Namespace(seed=args.seed, seconds=args.seconds, trace=0)
-    ctx = bench.Ctx(ns, files, cfg, devices)
+    ctx = bench.Ctx(ns, files, cfg, devices,
+                    bench.reference(files["config"]))
     serve = bench.load_module(bench.HERE / "kinds" / "serve.py")
     work = files["work"]
     if args.size_pool:

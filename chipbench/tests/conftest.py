@@ -1,9 +1,9 @@
 """CPU rehearsal of the benchmark at a reduced size.
 
 ``cell_root`` lays out a benchmark tree in a temporary directory: the
-real drivers (``kinds/``) and readers (``metrics/``), copied, and a
-reduced configuration, traffic mixes and cells of its own, with a
-``BENCHMARK.json`` naming them.  The chip gate is replaced from inside the
+real drivers (``kinds/``), readers (``metrics/``) and architecture modules
+(``references/``), copied, and a reduced configuration, traffic mixes and
+cells of its own, with a ``BENCHMARK.json`` naming them.  The chip gate is replaced from inside the
 test, so the drivers run end to end on the CPU with Pallas interpreted.
 """
 from __future__ import annotations

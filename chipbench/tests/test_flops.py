@@ -1,11 +1,15 @@
 """The shape-derived counts against XLA's own ``cost_analysis()`` on the
-CPU, and against counts by hand."""
+CPU, and against counts by hand; and the readers that divide by them."""
+import json
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+import bench
 import flops
 from conftest import CONFIG, SIZES
+from references import dense
 
 
 def xla_flops(fn, *args):
@@ -80,3 +84,56 @@ def test_matmul_params_match_the_program():
     n = sum(x.size for k, g in blocks.items() if k in ("attn", "mlp")
             for x in jax.tree.leaves(g))
     assert flops.matmul_params(SIZES) == n
+
+
+PHI3 = json.loads((bench.HERE / "configs" / "phi3-mini-3.8b.json")
+                  .read_text())["sizes"]
+
+
+def test_dense_counts_at_phi3_sizes():
+    """The counts the benchmark's readers divide by, at the phi3 sizes,
+    written as numbers: they never move with the harness's code."""
+    assert dense.prefill_flops(PHI3, 32768) == 448607383584768
+    assert dense.prefill_flops(PHI3, 4096) == 32986351140864
+    assert dense.prefill_attention(PHI3, 32768) == (211112674983936,
+                                                    25769803776)
+    assert dense.prefill_attention(PHI3, 4096) == (3299340189696,
+                                                   3221225472)
+    assert dense.tokens_flops(PHI3, 7, 300, unembedded=0) == 51551797248
+    assert dense.unembed_flops(PHI3) == 197001216
+    assert dense.paged_step(PHI3, [(1, 300), (64, 64), (16, 200)]) == (
+        2146959360, 253624320)
+
+
+class _Trace:
+    """A reduced trace whose attention kernels took 7.3964 s."""
+
+    def kernel_s(self, which):
+        return 7.3964
+
+
+@pytest.mark.parametrize("name,want", [
+    ("mfu.prefill", 24.932790224800918),
+    ("attn_roofline.prefill", 14.48864236765039),
+    ("mfu.serve", 1.6840733801692043),
+    ("paged_attn_roofline.serve", 0.006147193278662643),
+])
+def test_readers_divide_by_the_module_counts(name, want):
+    """With fixed data and the phi3 sizes, each reader that divides by a
+    count gives the number it gave when it read ``flops.py`` by name."""
+    data = {
+        "reference": dense, "sizes": PHI3, "device_kind": "TPU v5 lite",
+        "kind": "prefill", "trace": _Trace(),
+        "prefill": {"count": 3, "seq_len": 32768, "elapsed_s": 27.4,
+                    "traced": 1},
+        "steps": [
+            {"t0": 1.0, "t1": 1.05, "rows": [(1, 300), (64, 64)],
+             "tokens": 2},
+            {"t0": 1.05, "t1": 1.08, "rows": [(1, 301), (16, 200)],
+             "tokens": 1}],
+        "traced_steps": [{"rows": [(1, 300), (64, 64)]},
+                         {"rows": [(1, 301), (16, 200)]}],
+        "window": (0.9, 2.0),
+    }
+    reader = bench.load_module(bench.HERE / "metrics" / f"{name}.py")
+    assert reader.read(data) == want

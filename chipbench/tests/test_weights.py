@@ -1,13 +1,21 @@
 """The benchmark's weights and the plain reference, at a reduced size."""
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import weights as W
 from conftest import CONFIG, SIZES
 from references import dense as ref
 
 SEED = 2**31 + 99
+# sha256 of every leaf's bits, in tree order, of the dense weights at the
+# reduced size for SEED.  A seed's weights are fixed: a change to how the
+# harness finds or lays them out must not move what the benchmark reads.
+DENSE_DIGEST = \
+    "5e4c0a73593c6820184cef09266da974afac876488a920a873ead13ea802b7e4"
 
 
 def program_cfg(**kw):
@@ -19,10 +27,10 @@ def program_cfg(**kw):
 def test_layers_drawn_again_equal_the_stack():
     from repro.models import model as M
 
-    params = W.make(SIZES, SEED, M.param_specs(program_cfg()))
+    params = ref.make(SIZES, SEED, M.param_specs(program_cfg()))
     base = W.base_key(SEED)
     for i in range(SIZES["n_layers"]):
-        one = W.layer(SIZES, W.layer_key(base, i))
+        one = ref.layer(SIZES, W.layer_key(base, i))
         for a, b in zip(jax.tree.leaves(one),
                         jax.tree.leaves(jax.tree.map(lambda x: x[i],
                                                      params["blocks"]))):
@@ -30,7 +38,27 @@ def test_layers_drawn_again_equal_the_stack():
     V = SIZES["vocab_size"]
     emb = params["embed"]["embedding"]
     assert emb.shape[0] % 256 == 0 and (emb[V:] == 0).all()
-    assert (W.embedding(SIZES, base).astype(jnp.bfloat16) == emb[:V]).all()
+    assert (ref.embedding(SIZES, base).astype(jnp.bfloat16) == emb[:V]).all()
+
+
+def test_dense_weights_are_bit_identical_for_a_seed():
+    from repro.models import model as M
+
+    params = ref.make(SIZES, SEED, M.param_specs(program_cfg()))
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(params):
+        h.update(np.asarray(leaf).view(np.uint16).tobytes())
+    assert h.hexdigest() == DENSE_DIGEST
+
+
+def test_make_refuses_another_tree():
+    """``make`` raises where the program's parameters differ from the
+    layout it draws: here, a program with one more layer."""
+    from repro.models import model as M
+
+    spec = M.param_specs(program_cfg().with_(n_layers=SIZES["n_layers"] + 1))
+    with pytest.raises(ValueError, match="differs"):
+        ref.make(SIZES, SEED, spec)
 
 
 def test_values_have_the_stated_spread():
@@ -52,8 +80,8 @@ def test_weights_differ_by_seed():
     from repro.models import model as M
 
     spec = M.param_specs(program_cfg())
-    a = W.make(SIZES, 1, spec)["blocks"]["attn"]["wq"]
-    b = W.make(SIZES, 2, spec)["blocks"]["attn"]["wq"]
+    a = ref.make(SIZES, 1, spec)["blocks"]["attn"]["wq"]
+    b = ref.make(SIZES, 2, spec)["blocks"]["attn"]["wq"]
     assert not (a == b).all()
 
 
@@ -65,7 +93,7 @@ def test_reference_matches_the_program_in_float32():
 
     cfg = program_cfg(dtype="float32")
     sizes = dict(SIZES, dtype="float32")
-    params = W.make(sizes, SEED, M.param_specs(cfg))
+    params = ref.make(sizes, SEED, M.param_specs(cfg))
     toks = np.arange(300, dtype=np.int32) * 7 % SIZES["vocab_size"]
     with jax.default_matmul_precision("highest"):
         got = M.next_token_logits(cfg, params, {"tokens": jnp.asarray(

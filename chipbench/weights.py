@@ -1,28 +1,22 @@
-"""Seeded weights of a dense GQA decoder, made on the device.
+"""Seeded weights, made on the device: the parts no architecture owns.
 
 Each value is a hash of its place: the seed, the layer, the leaf and the
 element's flat index, mixed by integer arithmetic alone, then scaled once.
-So the reference can draw one layer again, bit for bit, without the rest:
-``layer(sizes, layer_key(base_key(seed), i))`` gives the values that
-``make`` put in layer ``i``, and the whole model costs one pass of integer
-work on the device rather than a normal sampler per element.
-
-Values are uniform with the standard deviation of the usual
-initialisation: 1/sqrt(fan_in) for matrices, 0.1 for norm scales (applied
-as ``1 + w``).  The vocabulary rows beyond ``vocab_size`` that the
-program's layout pads to are zero.
+So a reference can draw one layer again, bit for bit, without the rest,
+and a whole model costs one pass of integer work on the device rather
+than a normal sampler per element.  Each architecture module
+(``references/<name>.py``) lays out its own tree from ``uniform`` under
+keys from ``base_key``, ``layer_key`` and ``fold``, and checks it against
+the program's with ``check``.
 """
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-# tags of the leaves outside the layer stack (layers use 0..L-1)
-EMBED, LM_HEAD, FINAL_NORM = 1 << 20, (1 << 20) + 1, (1 << 20) + 2
 _M32 = 0xFFFFFFFF
 
 
@@ -78,84 +72,14 @@ def uniform(key, shape, std):
                                                   / (1 << 24))
 
 
-def gated(sizes: dict) -> bool:
-    return sizes["act"] in ("swiglu", "geglu")
-
-
-def layer(sizes: dict, key) -> dict:
-    """One layer's leaves in float32, in the program's layout: q/k/v/o
-    projections ``x @ w``, and an MLP whose ``w_in`` holds the up half and
-    then the gate half."""
-    d, hd, f = sizes["d_model"], sizes["head_dim"], sizes["d_ff"]
-    H, Kv = sizes["n_heads"], sizes["n_kv_heads"]
-    k = [fold(key, j) for j in range(8)]
-    return {
-        "ln1": {"w": uniform(k[0], (d,), 0.1)},
-        "ln2": {"w": uniform(k[1], (d,), 0.1)},
-        "attn": {
-            "wq": uniform(k[2], (d, H * hd), 1 / math.sqrt(d)),
-            "wk": uniform(k[3], (d, Kv * hd), 1 / math.sqrt(d)),
-            "wv": uniform(k[4], (d, Kv * hd), 1 / math.sqrt(d)),
-            "wo": uniform(k[5], (H * hd, d), 1 / math.sqrt(H * hd)),
-        },
-        "mlp": {
-            "w_in": uniform(k[6], (d, (2 if gated(sizes) else 1) * f),
-                            1 / math.sqrt(d)),
-            "w_out": uniform(k[7], (f, d), 1 / math.sqrt(f)),
-        },
-    }
-
-
-def embedding(sizes: dict, base):
-    return uniform(fold(base, EMBED), (sizes["vocab_size"], sizes["d_model"]),
-                   1 / math.sqrt(sizes["d_model"]))
-
-
-def lm_head(sizes: dict, base):
-    return uniform(fold(base, LM_HEAD),
-                   (sizes["d_model"], sizes["vocab_size"]),
-                   1 / math.sqrt(sizes["d_model"]))
-
-
-def final_norm(sizes: dict, base):
-    return uniform(fold(base, FINAL_NORM), (sizes["d_model"],), 0.1)
-
-
-def _freeze(sizes: dict):
+def freeze(sizes: dict):
+    """``sizes`` as a hashable static argument of a jitted function."""
     return tuple(sorted(sizes.items()))
 
 
-@partial(jax.jit, static_argnums=(0, 2, 3))
-def _make(frozen, base, vocab_rows: int, dtype: str):
-    sizes = dict(frozen)
-    pad = vocab_rows - sizes["vocab_size"]
-    keys = jax.vmap(lambda i: layer_key(base, i))(
-        jnp.arange(sizes["n_layers"], dtype=jnp.uint32))
-    blocks = jax.vmap(lambda k: jax.tree.map(lambda x: x.astype(dtype),
-                                             layer(sizes, k)))(keys)
-    return {
-        "embed": {
-            "embedding": jnp.pad(embedding(sizes, base).astype(dtype),
-                                 ((0, pad), (0, 0))),
-            "lm_head": jnp.pad(lm_head(sizes, base).astype(dtype),
-                               ((0, 0), (0, pad))),
-        },
-        "final_norm": {"w": final_norm(sizes, base).astype(dtype)},
-        "blocks": blocks,
-    }
-
-
-def make(sizes: dict, seed: int, spec):
-    """All weights in one jitted call, in the dtype and padded layout of
-    ``spec``, the program's parameter shapes (``ShapeDtypeStruct`` tree).
-
-    Raises where the program expects another tree, shape or dtype.
-    """
-    vocab_rows = spec["embed"]["embedding"].shape[0]
-    dtype = str(spec["embed"]["embedding"].dtype)
-    base = base_key(seed)
-    want = jax.eval_shape(lambda b: _make(_freeze(sizes), b, vocab_rows,
-                                          dtype), base)
+def check(want, spec) -> None:
+    """Raise where ``want``, the tree an architecture module would make
+    (``ShapeDtypeStruct`` leaves), differs from ``spec``, the program's."""
     if jax.tree.structure(want) != jax.tree.structure(spec):
         raise ValueError(f"the program's parameter tree differs: "
                          f"{jax.tree.structure(spec)} != "
@@ -165,4 +89,3 @@ def make(sizes: dict, seed: int, spec):
             raise ValueError(f"the program's parameter {b.shape} {b.dtype}"
                              f" differs from the benchmark's {a.shape}"
                              f" {a.dtype}")
-    return _make(_freeze(sizes), base, vocab_rows, dtype)
